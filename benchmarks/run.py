@@ -135,6 +135,9 @@ def main(argv: Optional[List[str]] = None) -> None:
         if unknown:
             ap.error(f"unknown bench(es) {sorted(unknown)}; known: {sorted(names)}")
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for spec in REGISTRY:
         if args.only and spec.name not in args.only:

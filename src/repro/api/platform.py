@@ -287,28 +287,31 @@ class DeviceMesh(Platform):
     def resources(self) -> Resources:
         """Per-device memory from ``device.memory_stats()``.
 
-        Forged host platforms (``xla_force_host_platform_device_count``)
-        and CPU backends don't expose memory stats — those devices fall
-        back to an equal slice of the host's physical RAM, so planning
-        against a forged mesh still sees finite, realistic capacities.
+        CPU devices (forged host platforms included) report no memory
+        limit; they fall back to an equal slice of the host's physical
+        RAM, so planning against a forged mesh still sees finite,
+        realistic capacities.  Any other device that reports no limit is
+        an error: host RAM says nothing about an accelerator's memory.
         """
         devs = self.devices()
         fallback = _host_memory_bytes() / max(len(devs), 1)
         mems: List[float] = []
         for d in devs:
-            m: Optional[float] = None
-            stats = getattr(d, "memory_stats", None)
-            if callable(stats):
-                try:
-                    s = stats()
-                    m = float(
-                        s.get("bytes_limit")
-                        or s.get("bytes_reservable_limit")
-                        or 0.0
-                    )
-                except Exception:
-                    m = None
-            mems.append(m if m else fallback)
+            cpu = getattr(d, "platform", "cpu") == "cpu"
+            try:
+                s = d.memory_stats() or {}
+            except Exception as e:
+                if not cpu:
+                    raise RuntimeError(f"{d}: memory_stats() failed: {e}") from e
+                s = {}
+            m = float(
+                s.get("bytes_limit") or s.get("bytes_reservable_limit") or 0.0
+            )
+            if not m:
+                if not cpu:
+                    raise RuntimeError(f"{d}: memory_stats() has no byte limit")
+                m = fallback
+            mems.append(m)
         return Resources(compute=self.profile(), memory=tuple(mems))
 
     def describe(self) -> str:

@@ -419,6 +419,7 @@ class Session:
         dashboard_port: Optional[int] = None,
         cluster=None,
         time_scale: float = 0.0,
+        timeout: Optional[float] = None,
     ) -> RunReport:
         """Serve a stream of tree requests on this platform.
 
@@ -447,7 +448,9 @@ class Session:
         problems return real factorizations in
         ``report.artifact[rid]``; ``time_scale`` > 0 paces submissions
         at ``arrival × time_scale`` wall seconds (0 = submit
-        immediately in arrival order).
+        immediately in arrival order), and ``timeout`` bounds the wait
+        for every result in wall seconds (default: 10 s per request,
+        at least 60 s).
 
         ``dashboard_port`` starts the live observability dashboard
         (``repro.obs.dashboard.Dashboard``) on that port (0 = auto) for
@@ -516,6 +519,7 @@ class Session:
                 qos_weights=qos_weights,
                 memory_budget=memory_budget,
                 time_scale=time_scale,
+                timeout=timeout,
             )
         report = serve_trees(
             reqs,
@@ -576,6 +580,7 @@ class Session:
         qos_weights,
         memory_budget,
         time_scale: float,
+        timeout: Optional[float],
     ) -> RunReport:
         """Serve the request list on a scheduler/worker cluster."""
         import math as _math
@@ -617,7 +622,9 @@ class Session:
                 engine.submit(
                     req.tree, tenant=req.tenant, rid=req.rid, alpha=alpha
                 )
-            results = engine.drain(timeout=max(60.0, 10.0 * len(reqs)))
+            if timeout is None:
+                timeout = max(60.0, 10.0 * len(reqs))
+            results = engine.drain(timeout=timeout)
             stats = engine.stats()
             sched_stats = engine.scheduler_stats()
         finally:

@@ -60,6 +60,7 @@ from repro.cluster.comm import (
 )
 from repro.core.graph import TaskTree
 from repro.core.pm import tree_equivalent_lengths, tree_pm_ratios
+from repro.distributed.device_groups import pow2_floor
 from repro.obs import events as obs_events
 from repro.obs import metrics as obs_metrics
 from repro.online.events import NoNoise
@@ -198,7 +199,13 @@ class ClusterScheduler:
         (matrix-free) trees consume it.
     ``tick``
         scheduler loop granularity in seconds.
+
+    A task whose dispatch fails on a worker is re-dispatched up to
+    ``MAX_RETRIES`` times; one more failure fails its tree, and the
+    request's future resolves ``ok=False`` with the worker's error.
     """
+
+    MAX_RETRIES = 2
 
     def __init__(
         self,
@@ -246,6 +253,8 @@ class ClusterScheduler:
         self.n_dispatches = 0
         self.n_requeued = 0
         self.n_worker_losses = 0
+        self.n_failed = 0
+        self._failures: Dict[Tuple[int, int], int] = {}  # task -> count
         self.batch_tenant_mix: List[int] = []  # distinct tenants per batch
         self._service_by_tenant: Dict[int, float] = {}
         self._prios: Dict[Tuple[int, int], Tuple[float, int]] = {}
@@ -615,6 +624,10 @@ class ClusterScheduler:
         if best is None:
             return None, None
         cap = self.max_batch if self.batching else 1
+        if best_key[0] == "front":
+            # power-of-two batches only, as in the executor: each batch
+            # size is its own compile, so a shape class has log2 of them
+            cap = pow2_floor(min(cap, len(best)))
         taken = best[:cap]
         del best[:cap]
         if not best:
@@ -702,10 +715,38 @@ class ClusterScheduler:
         self._dirty = True
 
     def _on_front_failed(self, msg: dict) -> None:
+        """Requeue a failed dispatch; a task that has failed more than
+        ``MAX_RETRIES`` times fails its tree with the worker's error."""
         batch = self.inflight.get(msg["batch"])
         if batch is None:
             return
         self._requeue(batch)
+        error = msg.get("error", "dispatch failed")
+        for tree_id, i in batch.items:
+            n = self._failures.get((tree_id, i), 0) + 1
+            self._failures[(tree_id, i)] = n
+            if n > self.MAX_RETRIES and tree_id in self.admitted:
+                self._fail_tree(
+                    self.trees[tree_id], f"task {i} failed {n} times, last on "
+                    f"{msg.get('worker')}: {error}"
+                )
+
+    def _fail_tree(self, e: _TreeEntry, error: str) -> None:
+        """Terminal failure: the tree leaves the forest and its client's
+        future resolves ``ok=False`` with ``error``."""
+        now = self._now()
+        e.run.fail(now, error)
+        self.admitted.discard(e.tree_id)
+        self.n_failed += 1
+        e.updates.clear()
+        fut = e.run.future
+        self._reply(e.client, {
+            "op": "tree-done", "ckey": e.ckey, "rid": fut.rid,
+            "tree_id": e.tree_id, "tenant": fut.tenant, "ok": False,
+            "error": error, "t_submit": fut.t_submit,
+            "t_admit": fut.t_admit, "t_done": now,
+        })
+        self._dirty = True
 
     def _finish_tree(self, e: _TreeEntry) -> None:
         now = self._now()
@@ -766,6 +807,7 @@ class ClusterScheduler:
             "n_reshares": self.n_reshares,
             "n_requeued": self.n_requeued,
             "n_worker_losses": self.n_worker_losses,
+            "n_failed": self.n_failed,
             "n_capacity_events": len(self.capacity_steps) - 1,
             "mean_latency": float(np.mean(lat)) if lat else 0.0,
         }
@@ -940,9 +982,10 @@ class ClusterClient:
                     )
                 f._resolve(TreeResult(
                     rid=f.rid, tenant=f.tenant, tree_id=int(msg["tree_id"]),
-                    ok=True, t_submit=msg["t_submit"],
+                    ok=bool(msg.get("ok", True)), t_submit=msg["t_submit"],
                     t_admit=msg["t_admit"], t_done=msg["t_done"],
                     spans=msg.get("tasks", []), factor=factor,
+                    error=msg.get("error"),
                 ))
             elif op == "stats-reply":
                 self._stats.put(msg["stats"])

@@ -7,8 +7,8 @@ for fronts ≤ VMEM_FRONT_MAX and the panel+SYRK pipeline above that, and
 slices the (panel, schur) outputs back to the caller's shapes.
 
 On non-TPU backends the kernels run in interpret mode (the body executes as
-plain JAX ops) — this is the CPU-container validation path; on TPU the same
-code lowers to Mosaic.
+plain JAX ops) — the CPU validation path; on TPU the same code lowers to
+Mosaic.  ``should_interpret`` is the one place that rule lives.
 """
 from __future__ import annotations
 
@@ -18,6 +18,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from .frontal_cholesky import (
     TILE,
@@ -30,7 +31,9 @@ from .frontal_cholesky import (
 OUTER_PANEL = 512  # large-front pivot panel width
 
 
-def _should_interpret(interpret: Optional[bool]) -> bool:
+def should_interpret(interpret: Optional[bool] = None) -> bool:
+    """Whether Pallas kernels run in interpret mode: an explicit choice
+    wins, otherwise exactly when JAX's default backend is not a TPU."""
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
@@ -98,7 +101,7 @@ def partial_cholesky(
     front: jax.Array, nb: int, interpret: Optional[bool] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Pallas-backed partial Cholesky: (panel (m,nb), schur (m−nb, m−nb))."""
-    return _partial_cholesky_impl(front, nb, _should_interpret(interpret))
+    return _partial_cholesky_impl(front, nb, should_interpret(interpret))
 
 
 def factor_fn(interpret: Optional[bool] = None):
@@ -168,19 +171,36 @@ def extract_panel_schur(
     return panel, schur
 
 
-@partial(jax.jit, static_argnames=("nbp", "interpret"))
-def _batched_front_factor(fronts: jax.Array, nbp: int, interpret: bool) -> jax.Array:
-    return jax.vmap(lambda f: front_factor_vmem(f, nbp, interpret=interpret))(fronts)
+@partial(jax.jit, static_argnames=("nbp", "interpret", "mesh"))
+def _batched_front_factor(
+    fronts: jax.Array, nbp: int, interpret: bool, mesh=None
+) -> jax.Array:
+    def factor(x):
+        return jax.vmap(lambda f: front_factor_vmem(f, nbp, interpret=interpret))(x)
+
+    if mesh is None:
+        return factor(fronts)
+    # GSPMD cannot partition a Mosaic call; shard_map gives each device
+    # its own lanes of the batch (lanes are independent fronts)
+    spec = PartitionSpec(mesh.axis_names[0])
+    return jax.shard_map(
+        factor, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
+    )(fronts)
 
 
 def batched_front_factor(
-    fronts: jax.Array, nbp: int, interpret: Optional[bool] = None
+    fronts: jax.Array,
+    nbp: int,
+    interpret: Optional[bool] = None,
+    mesh=None,
 ) -> jax.Array:
     """Factor a (B, mp, mp) stack of padded fronts in one vmapped kernel.
 
     Requires mp ≤ VMEM_FRONT_MAX (the executor routes larger fronts through
-    the per-front panel pipeline of ``partial_cholesky``).
+    the per-front panel pipeline of ``partial_cholesky``).  With a 1-D
+    ``mesh`` the batch axis is split over its devices (B must be a
+    multiple of the mesh size); each device factors its own lanes.
     """
     b, mp, mp2 = fronts.shape
     assert mp == mp2 and mp <= VMEM_FRONT_MAX and nbp % TILE == 0
-    return _batched_front_factor(fronts, nbp, _should_interpret(interpret))
+    return _batched_front_factor(fronts, nbp, should_interpret(interpret), mesh)

@@ -66,9 +66,9 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.distributed.device_groups import (
     BuddyAllocator,
@@ -87,6 +87,7 @@ from repro.kernels.ops import (
     pad_front_np,
     padded_shape,
     partial_cholesky,
+    should_interpret,
 )
 from repro.sparse.multifrontal import (
     Factorization,
@@ -159,6 +160,9 @@ class ExecutionReport:
     measured_peak_bytes: float = 0.0
     projected_peak_bytes: float = 0.0
     mode: str = "waves"  # which runner produced this report
+    # perf_counter() at the start of the timed run: trace times are
+    # seconds since then, so the window is [t_origin, + measured_makespan]
+    t_origin: float = math.nan
 
     # ------------------------------------------------------------------
     def total_flops(self) -> float:
@@ -363,11 +367,7 @@ class PlanExecutor:
         self.symb = symb
         self.plan = plan
         self.devices = list(devices) if devices is not None else jax.devices()
-        self.interpret = (
-            interpret
-            if interpret is not None
-            else jax.default_backend() != "tpu"
-        )
+        self.interpret = should_interpret(interpret)
         if dtype is None:
             dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
         self.dtype = np.dtype(dtype)
@@ -506,24 +506,33 @@ class PlanExecutor:
         the factored stack (host)."""
         mp = batch.shape[1]
         assert mp <= VMEM_FRONT_MAX, "large fronts take the per-front path"
-        x = jnp.asarray(batch)
         if len(group_devices) > 1 and self.shard_dispatch:
-            from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
             ndev = len(group_devices)
             pad = (-batch.shape[0]) % ndev
+            x = batch
             if pad:
                 eye = np.broadcast_to(
                     np.eye(mp, dtype=batch.dtype), (pad, mp, mp)
                 )
-                x = jnp.concatenate([x, jnp.asarray(eye)], axis=0)
+                x = np.concatenate([batch, eye], axis=0)
             mesh = Mesh(np.array(group_devices), ("front",))
-            x = jax.device_put(x, NamedSharding(mesh, P("front")))
-            out = batched_front_factor(x, nbp, self.interpret)
+            # host → each device's own lanes; the kernel runs under
+            # shard_map, one Mosaic call per device on its local lanes
+            x = jax.device_put(x, NamedSharding(mesh, PartitionSpec("front")))
+            out = batched_front_factor(x, nbp, self.interpret, mesh=mesh)
             out = np.asarray(jax.block_until_ready(out))
             return out[: batch.shape[0]]
+        x = jax.device_put(batch, group_devices[0])
         out = batched_front_factor(x, nbp, self.interpret)
         return np.asarray(jax.block_until_ready(out))
+
+    def _run_large(self, front: np.ndarray, nb: int, device):
+        """One front above VMEM_FRONT_MAX through the panel pipeline on
+        ``device``; returns host (panel, schur)."""
+        panel, schur = partial_cholesky(
+            jax.device_put(front, device), nb, interpret=self.interpret
+        )
+        return np.asarray(jax.block_until_ready(panel)), np.asarray(schur)
 
     def warmup(
         self,
@@ -559,15 +568,19 @@ class PlanExecutor:
         """Compile the async runner's dispatch signatures (untimed).
 
         Async batches are truncated to power-of-two sizes, so per shape
-        class only ``log2`` batch signatures exist; with sharding off
-        (the interpret-mode default) the device identity drops out of
-        the jit key and this coverage is *exact* — no compile ever lands
-        inside the timed region."""
+        class only ``log2`` batch signatures exist, compiled here on every
+        device a single-device batch can land on.  With sharding off (the
+        interpret-mode default) the coverage is *exact*; with it on,
+        batches sharded over a device subset compile on first use, and
+        so do fronts above VMEM_FRONT_MAX."""
         counts: Dict[Tuple[int, int], int] = {}
         for sn in self.symb.supernodes:
             key = padded_shape(sn.m, sn.nb)
             if key[0] <= VMEM_FRONT_MAX:
                 counts[key] = counts.get(key, 0) + 1
+        # single-device batches run on whichever device their group was
+        # carved from, and each placement is its own executable
+        devices = self.devices if self.shard_dispatch else self.devices[:1]
         for (mp, nbp), c in sorted(counts.items()):
             b = 1
             cap = _pow2_ceil(min(c, self.max_batch))
@@ -575,7 +588,8 @@ class PlanExecutor:
                 eye = np.broadcast_to(
                     np.eye(mp, dtype=self.dtype), (b, mp, mp)
                 ).copy()
-                self._run_batch(eye, nbp, self.devices[:1])
+                for dev in devices:
+                    self._run_batch(eye, nbp, [dev])
                 b *= 2
 
     def _dispatch_devices(
@@ -714,6 +728,7 @@ class PlanExecutor:
             measured_peak_bytes=float(mem_peak),
             projected_peak_bytes=float(projected_peak),
             mode=mode,
+            t_origin=self._obs_t0,
         )
 
     # -- wave runner (legacy, barrier-synchronous) ---------------------
@@ -771,16 +786,8 @@ class PlanExecutor:
                 # large fronts: per-front panel+SYRK pipeline
                 for s, f in zip(d.supernodes, fronts):
                     sn = symb.supernodes[s]
-                    panel, schur = partial_cholesky(
-                        jnp.asarray(f), sn.nb, interpret=self.interpret
-                    )
-                    self._store(
-                        s,
-                        np.asarray(jax.block_until_ready(panel)),
-                        np.asarray(schur),
-                        panels,
-                        updates,
-                    )
+                    panel, schur = self._run_large(f, sn.nb, disp_devs[0])
+                    self._store(s, panel, schur, panels, updates)
                 t1 = time.perf_counter() - t_run0
             else:
                 batch = np.stack(
@@ -843,9 +850,9 @@ class PlanExecutor:
         ndev = len(self.devices)
         by_task = {t.label: t for t in self.plan.tasks if t.label >= 0}
         if warmup:
+            # pow-2 batch sizes only; the wave schedule's sizes, which
+            # ``warmup()`` compiles, are never dispatched here
             self._warmup_async()
-            if self.shard_dispatch:
-                self.warmup()  # plan-derived sharded signatures too
         projected_peak = self._projected_peak()
 
         n = symb.n_supernodes
@@ -941,20 +948,15 @@ class PlanExecutor:
             out = self._run_batch(batch, nbp, devs)
             return {"out": out, "t0": t0, "t1": now()}
 
-        def worker_large(items, delay):
+        def worker_large(items, device, delay):
             # items: [(supernode, front)] — per-front panel+SYRK pipeline
             t0 = now()
             if delay > 0:
                 time.sleep(delay)
-            outs = []
-            for s, f in items:
-                sn = symb.supernodes[s]
-                panel, schur = partial_cholesky(
-                    jnp.asarray(f), sn.nb, interpret=self.interpret
-                )
-                outs.append(
-                    (np.asarray(jax.block_until_ready(panel)), np.asarray(schur))
-                )
+            outs = [
+                self._run_large(f, symb.supernodes[s].nb, device)
+                for s, f in items
+            ]
             return {"outs": outs, "t0": t0, "t1": now()}
 
         def launch_ready(pool) -> int:
@@ -1046,7 +1048,10 @@ class PlanExecutor:
                     held = fronts_bytes
                     disp_dev = 1
                     fut = pool.submit(
-                        worker_large, list(zip(members, fronts)), delay
+                        worker_large,
+                        list(zip(members, fronts)),
+                        self._dispatch_devices(members, groups)[0],
+                        delay,
                     )
                 else:
                     batch = np.stack(
@@ -1167,6 +1172,7 @@ class PlanExecutor:
         gid: int,
         acsc: sp.csc_matrix,
         ext_cb: Dict[int, Tuple[np.ndarray, np.ndarray]],
+        group: Optional[DeviceGroup] = None,
     ) -> Dict:
         """Factor one fused group's member fronts; the worker body shared
         by both provenance runners (pure compute — no shared state is
@@ -1182,11 +1188,22 @@ class PlanExecutor:
         ``assemble_front_np`` with its children folded in tree order —
         the bit-identity discipline of ``_assemble``, unchanged.
 
+        The members run on the carved ``group`` of devices (batches
+        sharded over it when sharding is on), on the first device when
+        none was carved.
+
         Returns per-member ``(s, panel, schur)`` (``schur`` only for
         members whose parent lies outside the group), the dispatch's
         wall-clock interval, and the transient byte peak the group held.
         """
         symb = self.symb
+        devs = (
+            self.devices[group.offset : group.offset + group.size]
+            if group is not None
+            else self.devices[:1]
+        )
+        if not self.shard_dispatch:
+            devs = devs[:1]
         members = self._groups[gid]
         inset = set(members)
         cb = dict(ext_cb)
@@ -1230,16 +1247,12 @@ class PlanExecutor:
                 if mp > VMEM_FRONT_MAX:
                     for s in sns:
                         sn = symb.supernodes[s]
-                        panel, schur = partial_cholesky(
-                            jnp.asarray(fronts[s]),
-                            sn.nb,
-                            interpret=self.interpret,
+                        panel, schur = self._run_large(
+                            fronts[s], sn.nb, devs[0]
                         )
-                        panels_local[s] = np.asarray(
-                            jax.block_until_ready(panel)
-                        )
+                        panels_local[s] = panel
                         if sn.m > sn.nb:
-                            cb[s] = (sn.rows[sn.nb :], np.asarray(schur))
+                            cb[s] = (sn.rows[sn.nb :], schur)
                     continue
                 for lo in range(0, len(sns), self.max_batch):
                     chunk = sns[lo : lo + self.max_batch]
@@ -1260,7 +1273,7 @@ class PlanExecutor:
                         )
                         batch = np.concatenate([batch, eye], axis=0)
                     peak = max(peak, held + float(batch.nbytes))
-                    out = self._run_batch(batch, nbp, self.devices[:1])
+                    out = self._run_batch(batch, nbp, devs)
                     for s, o in zip(chunk, out[:k]):
                         sn = symb.supernodes[s]
                         panel, schur = extract_panel_schur(o, sn.m, sn.nb)
@@ -1285,6 +1298,7 @@ class PlanExecutor:
             "t0": t0,
             "t1": time.perf_counter(),
             "transient": peak,
+            "dispatch_devices": len(devs),
         }
 
     def _pop_ext_cb(
@@ -1340,6 +1354,7 @@ class PlanExecutor:
         self._mem_updates = 0.0
         mem_peak = 0.0
         t_run0 = time.perf_counter()
+        self._obs_t0 = t_run0
 
         for w, wave in enumerate(self.plan.waves()):
             for t in sorted(wave, key=lambda t: t.task):
@@ -1347,7 +1362,8 @@ class PlanExecutor:
                     continue
                 gid = t.label
                 ext_cb, consumed = self._pop_ext_cb(gid, updates)
-                res = self._run_group(gid, acsc, ext_cb)
+                g = groups.get(gid)
+                res = self._run_group(gid, acsc, ext_cb, g)
                 mem_peak = max(
                     mem_peak,
                     self._mem_panels + self._mem_updates + res["transient"],
@@ -1355,7 +1371,6 @@ class PlanExecutor:
                 self._mem_updates -= consumed
                 self._store_group(res, panels, updates)
                 n_disp += 1
-                g = groups.get(gid)
                 t0 = res["t0"] - t_run0
                 t1 = res["t1"] - t_run0
                 for s in self._groups[gid]:
@@ -1365,7 +1380,7 @@ class PlanExecutor:
                             wave=w,
                             devices=t.devices,
                             devices_used=g.size if g else 1,
-                            dispatch_devices=1,
+                            dispatch_devices=res["dispatch_devices"],
                             t_start=t0,
                             t_end=t1,
                             flops=symb.supernodes[s].flops,
@@ -1435,6 +1450,7 @@ class PlanExecutor:
         n_disp = 0
         seq = 0
         t_run0 = time.perf_counter()
+        self._obs_t0 = t_run0
 
         def now() -> float:
             return time.perf_counter() - t_run0
@@ -1521,7 +1537,7 @@ class PlanExecutor:
                 )
                 self._mem_updates -= consumed
                 mem_inflight += held
-                fut = pool.submit(self._run_group, gid, acsc, ext_cb)
+                fut = pool.submit(self._run_group, gid, acsc, ext_cb, g_alloc)
                 in_flight[fut] = (gid, g_alloc, held, t_sub, seq)
                 seq += 1
                 n_disp += 1
@@ -1553,7 +1569,7 @@ class PlanExecutor:
                         wave=sq,
                         devices=by_task[gid].devices if gid in by_task else 1,
                         devices_used=g_alloc.size,
-                        dispatch_devices=1,
+                        dispatch_devices=res["dispatch_devices"],
                         t_start=t0,
                         t_end=t1,
                         flops=symb.supernodes[s].flops,

@@ -38,6 +38,29 @@ class Factorization:
                 l[rows, j] = panel[pos, k]
         return l
 
+    def to_sparse_l(self) -> sp.csc_matrix:
+        """L as a sparse (n, n) CSC matrix in float64 — the same entries
+        as ``to_dense_l`` in O(nnz(L)) memory."""
+        rows, cols, vals = [], [], []
+        for sn, panel in zip(self.symb.supernodes, self.panels):
+            r, k = np.nonzero(sn.rows[:, None] >= sn.cols[None, :])
+            rows.append(sn.rows[r])
+            cols.append(sn.cols[k])
+            vals.append(np.asarray(panel, dtype=np.float64)[r, k])
+        n = self.symb.n
+        return sp.csc_matrix(
+            (np.concatenate(vals), (np.concatenate(rows), np.concatenate(cols))),
+            shape=(n, n),
+        )
+
+    def backward_error(self, a: sp.spmatrix) -> float:
+        """‖LLᵀ − A‖_F / ‖A‖_F against ``a`` (in the factor's ordering),
+        computed sparse in float64."""
+        l = self.to_sparse_l()
+        a = sp.csr_matrix(a, dtype=np.float64)
+        r = (l @ l.T - a).tocsr()
+        return float(np.linalg.norm(r.data) / np.linalg.norm(a.data))
+
 
 def gather_front_entries(a: sp.csc_matrix, sn: Supernode) -> np.ndarray:
     """Dense (m, m) block with original entries of the pivot columns/rows.

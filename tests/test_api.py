@@ -434,6 +434,28 @@ def test_platform_resources_views():
     assert all(np.isfinite(m) and m > 0 for m in dm.memory)
 
 
+class _Accel:
+    """A stand-in accelerator device with a given memory_stats()."""
+
+    platform = "tpu"
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        if isinstance(self._stats, Exception):
+            raise self._stats
+        return self._stats
+
+
+def test_device_mesh_accelerator_memory_has_no_host_fallback():
+    dm = DeviceMesh([_Accel({"bytes_limit": 16e9})] * 2).resources()
+    assert dm.memory == (16e9, 16e9)
+    for bad in (RuntimeError("no stats"), {}, None):
+        with pytest.raises(RuntimeError, match="memory_stats"):
+            DeviceMesh([_Accel(bad)]).resources()
+
+
 def test_problem_footprints_from_symbolic_and_override(rng):
     prob = grid_problem(11)
     fp = prob.memory_footprints()

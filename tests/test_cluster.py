@@ -408,6 +408,33 @@ def test_numeric_worker_kill_factors_survive():
     assert leaked_threads() == []
 
 
+def test_failing_kernel_resolves_future_with_worker_error(rng, monkeypatch):
+    """A kernel that keeps raising on the worker (a refused compile, say)
+    is retried a bounded number of times; then the request's future
+    resolves ok=False with the worker's error text instead of being
+    requeued forever, and other tenants' trees still complete."""
+    import repro.kernels.ops as ops
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("kernel refused by the compiler")
+
+    monkeypatch.setattr(ops, "batched_front_factor", refuse)
+    monkeypatch.setattr(ops, "partial_cholesky", refuse)
+    with LocalCluster(n_workers=2, **FAST, **HB) as cl:
+        client = cl.client()
+        bad = client.submit(_grid_problem(), tenant=0, rid=0)
+        good = client.submit(_trees(rng, 1)[0], tenant=1, rid=1)
+        r_bad, r_good = client.gather([bad, good], timeout=60.0)
+        assert not r_bad.ok
+        assert "RuntimeError: kernel refused by the compiler" in r_bad.error
+        assert f"failed {ClusterScheduler.MAX_RETRIES + 1} times" in r_bad.error
+        assert r_good.ok
+        stats = cl.scheduler.stats()
+        assert stats["n_failed"] == 1
+        assert stats["n_admitted"] == 0
+    assert leaked_threads() == []
+
+
 # ----------------------------------------------------------------------
 # Session facade
 # ----------------------------------------------------------------------

@@ -139,8 +139,9 @@ def test_assign_wave_groups_oversubscribed():
 
 @pytest.mark.slow
 def test_executor_multi_device_forged():
-    """Sharded wave dispatch on 4 forged CPU devices (subprocess owns the
-    XLA device-forging flag before jax initializes)."""
+    """Device-group execution on 4 forged CPU devices, unsharded and
+    sharded (subprocess owns the XLA device-forging flag before jax
+    initializes)."""
     code = """
 import jax
 jax.config.update("jax_enable_x64", True)
@@ -160,6 +161,13 @@ l = fact.to_dense_l()
 assert np.abs(l @ l.T - dense).max() / np.abs(dense).max() < 1e-5
 used = {e.devices_used for e in rep.trace}
 assert max(used) > 1, used  # groups actually span devices
+# sharded dispatch: each device factors its own lanes under shard_map,
+# batches land on their carved devices; lanes are independent fronts,
+# so the factor bits match the unsharded run
+for mode in ("async", "waves"):
+    f_s, r_s = execute_plan(ap, symb, plan, shard_dispatch=True, mode=mode)
+    assert all(np.array_equal(p, q) for p, q in zip(fact.panels, f_s.panels))
+    assert max(e.dispatch_devices for e in r_s.trace) > 1
 print("MULTIDEV_OK", sorted(used), rep.fit_alpha())
 """
     env = dict(os.environ)
@@ -174,3 +182,21 @@ print("MULTIDEV_OK", sorted(used), rep.fit_alpha())
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "MULTIDEV_OK" in out.stdout
+
+
+def test_compile_cache_dir_env_or_checkout(monkeypatch):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, else to the checkout's fixed .jax_cache."""
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere/cache"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = os.path.join(REPO, ".jax_cache")
+        assert enable_compile_cache() == path
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

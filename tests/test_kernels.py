@@ -4,6 +4,8 @@ Sweeps shapes and dtypes in interpret mode (CPU container; on TPU the same
 calls lower to Mosaic).  Covers both execution paths: the VMEM-resident
 whole-front kernel and the panel+SYRK large-front pipeline.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -111,6 +113,28 @@ def test_padding_pivots_are_inert(rng):
     assert np.abs(np.asarray(sch) - np.asarray(sr)).max() / max(
         1.0, float(jnp.abs(sr).max())
     ) < 5e-5
+
+
+@pytest.mark.parametrize(
+    "kernel,shapes",
+    [
+        (lambda f: ops.front_factor_vmem(f, 128), [(256, 256)]),
+        (panel_factor, [(256, 128)]),
+        (lambda c, a: syrk_downdate(c, a, tile=128), [(256, 256), (256, 128)]),
+    ],
+    ids=["front_factor_vmem", "panel_factor", "syrk_downdate"],
+)
+def test_kernel_matmuls_contract_at_full_f32_precision(kernel, shapes):
+    """Every MXU contraction in the front kernels asks for HIGHEST
+    precision.  Interpret mode cannot show the difference, but on a v5e
+    Mosaic's default (one bf16 pass) left a backward error of 5.5e-4 on
+    a 65k-unknown 2D grid, far above the 100·eps_f32 tolerance."""
+    args = [jnp.zeros(s, jnp.float32) for s in shapes]
+    text = str(jax.make_jaxpr(kernel)(*args))
+    dots = re.findall(r"dot_general\[(.*?)preferred_element_type", text, flags=re.S)
+    assert dots
+    for params in dots:
+        assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" in params
 
 
 # ----------------------------------------------------------------------

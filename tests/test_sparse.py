@@ -63,6 +63,30 @@ def test_grid_3d_factorization():
     assert np.abs(l @ l.T - a.toarray()).max() < 1e-10
 
 
+@pytest.mark.parametrize("relax", [0, 2])
+def test_sparse_l_and_backward_error_match_dense(relax):
+    """The sparse export holds exactly the dense factor's entries, and
+    the sparse ‖LLᵀ−A‖_F/‖A‖_F equals the dense computation — also for
+    a factor perturbed far from exact."""
+    a = grid_laplacian_2d(11, 9)
+    ap = permute_symmetric(a, nested_dissection_2d(11, 9))
+    symb = analyze(ap, relax=relax)
+    fact = factorize(ap, symb)
+    l = fact.to_dense_l()
+    np.testing.assert_array_equal(fact.to_sparse_l().toarray(), l)
+    dense = ap.toarray()
+
+    def dense_err(l):
+        return np.linalg.norm(l @ l.T - dense) / np.linalg.norm(dense)
+
+    assert fact.backward_error(ap) == pytest.approx(dense_err(l), abs=1e-15)
+    assert fact.backward_error(ap) < 1e-14
+    fact.panels[0] = fact.panels[0] * 1.01
+    l = fact.to_dense_l()
+    assert fact.backward_error(ap) == pytest.approx(dense_err(l), rel=1e-12)
+    assert fact.backward_error(ap) > 1e-4
+
+
 def test_random_spd_min_degree(rng):
     a = random_spd(50, 4.0, rng)
     p = min_degree(a)
