@@ -1,0 +1,11 @@
+"""Host self time of the executor's gather stage (``executor.gather``: each
+dispatch's fronts take their entries of A), in seconds per factorization of
+the traced window (``program_spans.py``)."""
+import runpy
+from pathlib import Path
+
+SPANS = runpy.run_path(str(Path(__file__).resolve().parents[1] / "program_spans.py"))
+
+
+def read(run):
+    return SPANS["stage_seconds"](run, ("executor.gather",))

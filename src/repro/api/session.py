@@ -337,6 +337,12 @@ class Session:
         fluid schedule is discretized on the way (exact pass-through
         for ``greedy``-family schedules and shipped-JSON plans).
         """
+        from repro.obs.events import stage
+
+        with stage("session.execute"):
+            return self._execute(warmup, mode, executor_kwargs)
+
+    def _execute(self, warmup: bool, mode: str, executor_kwargs) -> RunReport:
         from repro.runtime.executor import PlanExecutor
 
         problem = self._require_problem()
@@ -452,7 +458,8 @@ class Session:
         for every result in wall seconds (default: 10 s per request,
         at least 60 s).
 
-        ``dashboard_port`` starts the live observability dashboard
+        ``dashboard_port`` turns the telemetry bus on (``obs.enable()``)
+        and starts the live observability dashboard
         (``repro.obs.dashboard.Dashboard``) on that port (0 = auto) for
         the duration of the serve loop and leaves it running on
         ``self.dashboard`` afterwards — browse ``self.dashboard.url``,
@@ -464,7 +471,10 @@ class Session:
         from repro.online.queue import TreeRequest, serve_trees
 
         if dashboard_port is not None:
+            from repro import obs
             from repro.obs.dashboard import Dashboard
+
+            obs.enable()  # the dashboard shows what the bus records
 
             if self.dashboard is not None:  # no port squatting across serves
                 self.dashboard.stop()

@@ -123,6 +123,7 @@ def front_factor_vmem(
         out_specs=pl.BlockSpec((mp, mp), lambda: (0, 0)),
         compiler_params=_FRONT_PARAMS,
         interpret=interpret,
+        name="front_factor_vmem",
     )(front)
 
 
@@ -168,6 +169,7 @@ def panel_factor(slab: jax.Array, interpret: bool = False) -> jax.Array:
         out_specs=pl.BlockSpec((mp, nb), lambda: (0, 0)),
         compiler_params=_FRONT_PARAMS,
         interpret=interpret,
+        name="panel_factor",
     )(slab)
 
 
@@ -208,4 +210,5 @@ def syrk_downdate(
         out_specs=pl.BlockSpec((tile, tile), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, m), c.dtype),
         interpret=interpret,
+        name="syrk_downdate",
     )(a, a, c)

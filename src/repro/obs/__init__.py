@@ -17,14 +17,17 @@ One substrate for everything the repo measures about itself:
 Quick start::
 
     from repro import obs
+    obs.enable()                  # the bus and the registry are off by default
     obs.BUS.clear(); obs.REGISTRY.reset()
     ... run something instrumented ...
     obs.save_trace(obs.from_bus(obs.BUS), "run.trace.json")
     obs.save_html_report("run.html")
 
-``obs.disable()`` turns every publish site into an immediate return —
-numeric results are bit-identical with telemetry off (enforced by
-``tests/test_obs.py``).
+Until ``obs.enable()`` (and after ``obs.disable()``) every publish site
+is an immediate return — numeric results are bit-identical with
+telemetry off (enforced by ``tests/test_obs.py``).  ``obs.stage(name)``
+marks a stage of host work on an active ``jax.profiler`` trace whether
+or not the bus is on.
 """
 from .dashboard import Dashboard, render_html, save_html_report
 from .efficiency import (
@@ -52,6 +55,7 @@ from .events import (
     enable,
     enabled,
     get_bus,
+    stage,
 )
 from .metrics import (
     DEFAULT_BUCKETS,
@@ -122,4 +126,5 @@ __all__ = [
     "schedule_l2_deviation",
     "schedule_share_timeline",
     "slice_event",
+    "stage",
 ]

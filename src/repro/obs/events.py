@@ -23,11 +23,20 @@ simulated runs (the discrete-event online scheduler) stamp *virtual*
 time.  Every record carries its ``clock`` so the two never mix silently;
 exporters and metrics group by clock domain.
 
-**Zero-overhead mode.**  ``obs.disable()`` flips one module flag; every
-publish method returns immediately.  Instrumented code may also guard
+**Off by default.**  The bus records nothing until ``obs.enable()``
+(``Session.serve(dashboard_port=...)`` calls it when it starts the
+dashboard); until then every publish method returns immediately, and
+``obs.disable()`` flips it back.  Instrumented code may also guard
 larger blocks with :func:`enabled`.  Publishing never mutates numeric
-state anywhere — disabling telemetry must (and does — see
+state anywhere — recording telemetry must (and does — see
 ``tests/test_obs.py``) leave factorization bits identical.
+
+**Stages on the profiler's clock.**  :class:`stage` names one stage of
+the host's work.  It always enters a ``jax.profiler.TraceAnnotation``,
+so an active profiler trace holds the stage on the host plane, on the
+same clock as the device's operations; with no trace active that costs
+about a microsecond.  When the bus is enabled, the stage is also a
+``Span`` of category ``stage`` on the bus.
 
 The bus is process-local and thread-safe (the async executor publishes
 from worker threads).  It is *not* a metrics store — counters, gauges
@@ -272,7 +281,45 @@ class EventBus:
 # The process-local bus and the zero-overhead switch
 # ----------------------------------------------------------------------
 BUS = EventBus()
-_ENABLED = [True]  # single-cell so instrumented code sees flips instantly
+_ENABLED = [False]  # single-cell so instrumented code sees flips instantly
+_ANNOTATION: List = []  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def stage(name: str, **attrs):
+    """``with stage(name, **attrs):`` — one named stage of host work.
+
+    Always a ``jax.profiler.TraceAnnotation`` (a span on the profiler's
+    host plane, on the device trace's clock, when a trace is active);
+    also a bus ``Span`` (``cat="stage"``, ``key`` = the OS thread id)
+    when the bus is enabled.  Stages nest per thread.
+    """
+    if not _ANNOTATION:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION.append(TraceAnnotation)
+    ann = _ANNOTATION[0](name, **attrs)
+    return _BusStage(ann, name, attrs) if _ENABLED[0] else ann
+
+
+class _BusStage:
+    """A stage that is also recorded on the bus."""
+
+    __slots__ = ("ann", "name", "attrs", "t0")
+
+    def __init__(self, ann, name: str, attrs: Dict) -> None:
+        self.ann, self.name, self.attrs = ann, name, attrs
+
+    def __enter__(self) -> "_BusStage":
+        self.ann.__enter__()
+        self.t0 = BUS.wall()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        BUS.span(
+            self.name, self.t0, BUS.wall(), cat="stage",
+            key=threading.get_native_id(), **self.attrs,
+        )
+        self.ann.__exit__(*exc)
 
 
 def get_bus() -> EventBus:
@@ -289,7 +336,8 @@ def enable() -> None:
 
 
 def disable() -> None:
-    """Zero-overhead mode: every publish becomes an immediate return.
+    """Zero-overhead mode (the default): every publish becomes an
+    immediate return.
 
     Numeric results are unaffected by construction — publishers never
     read the bus back into computation.
@@ -309,4 +357,5 @@ __all__ = [
     "enable",
     "enabled",
     "get_bus",
+    "stage",
 ]

@@ -81,6 +81,7 @@ from repro.kernels.frontal_cholesky import VMEM_FRONT_MAX
 from repro.obs import events as obs_events
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.obs.events import stage
 from repro.kernels.ops import (
     batched_front_factor,
     extract_panel_schur,
@@ -91,7 +92,8 @@ from repro.kernels.ops import (
 )
 from repro.sparse.multifrontal import (
     Factorization,
-    assemble_front_np,
+    extend_add_np,
+    gather_front_entries,
     lower_csc,
 )
 from repro.sparse.plan import ExecutionPlan
@@ -518,21 +520,36 @@ class PlanExecutor:
             mesh = Mesh(np.array(group_devices), ("front",))
             # host → each device's own lanes; the kernel runs under
             # shard_map, one Mosaic call per device on its local lanes
-            x = jax.device_put(x, NamedSharding(mesh, PartitionSpec("front")))
-            out = batched_front_factor(x, nbp, self.interpret, mesh=mesh)
-            out = np.asarray(jax.block_until_ready(out))
-            return out[: batch.shape[0]]
-        x = jax.device_put(batch, group_devices[0])
-        out = batched_front_factor(x, nbp, self.interpret)
-        return np.asarray(jax.block_until_ready(out))
+            with stage("executor.h2d"):
+                x = jax.device_put(
+                    x, NamedSharding(mesh, PartitionSpec("front"))
+                )
+            with stage("executor.kernel"):
+                out = jax.block_until_ready(
+                    batched_front_factor(x, nbp, self.interpret, mesh=mesh)
+                )
+            with stage("executor.d2h"):
+                return np.asarray(out)[: batch.shape[0]]
+        with stage("executor.h2d"):
+            x = jax.device_put(batch, group_devices[0])
+        with stage("executor.kernel"):
+            out = jax.block_until_ready(
+                batched_front_factor(x, nbp, self.interpret)
+            )
+        with stage("executor.d2h"):
+            return np.asarray(out)
 
     def _run_large(self, front: np.ndarray, nb: int, device):
         """One front above VMEM_FRONT_MAX through the panel pipeline on
         ``device``; returns host (panel, schur)."""
-        panel, schur = partial_cholesky(
-            jax.device_put(front, device), nb, interpret=self.interpret
-        )
-        return np.asarray(jax.block_until_ready(panel)), np.asarray(schur)
+        with stage("executor.h2d"):
+            x = jax.device_put(front, device)
+        with stage("executor.kernel"):
+            panel, schur = jax.block_until_ready(
+                partial_cholesky(x, nb, interpret=self.interpret)
+            )
+        with stage("executor.d2h"):
+            return np.asarray(panel), np.asarray(schur)
 
     def warmup(
         self,
@@ -640,13 +657,14 @@ class PlanExecutor:
         futures runner or the legacy wave runner per ``self.mode``; an
         amalgamated plan (``provenance=``) takes the group-dispatch
         variants of the same two runners."""
-        if self._prov is not None:
+        with stage("executor.run"):
+            if self._prov is not None:
+                if self.mode == "waves":
+                    return self._run_waves_prov(a, warmup)
+                return self._run_async_prov(a, warmup)
             if self.mode == "waves":
-                return self._run_waves_prov(a, warmup)
-            return self._run_async_prov(a, warmup)
-        if self.mode == "waves":
-            return self._run_waves(a, warmup)
-        return self._run_async(a, warmup)
+                return self._run_waves(a, warmup)
+            return self._run_async(a, warmup)
 
     # -- shared numeric helpers ----------------------------------------
     def _assemble(
@@ -656,24 +674,35 @@ class PlanExecutor:
         panels: List[Optional[np.ndarray]],
         updates: Dict[int, Tuple[np.ndarray, np.ndarray]],
     ) -> Tuple[np.ndarray, float]:
-        """Assemble front ``s`` (original entries + children extend-add),
-        popping — i.e. freeing — the children's Schur buffers.  Returns
-        (front, consumed CB bytes).  Children are folded in tree order
-        regardless of completion order, so the float summation order (and
-        therefore the factor bits) is identical across modes."""
+        """Assemble front ``s``: its original entries, then the children's
+        extend-add (``_extend_add``).  Returns (front, consumed CB bytes)."""
+        f = gather_front_entries(acsc, self.symb.supernodes[s])
+        return self._extend_add(s, f, panels, updates)
+
+    def _extend_add(
+        self,
+        s: int,
+        f: np.ndarray,
+        panels: List[Optional[np.ndarray]],
+        updates: Dict[int, Tuple[np.ndarray, np.ndarray]],
+    ) -> Tuple[np.ndarray, float]:
+        """Fold the children's Schur complements into front ``s``'s
+        gathered entries ``f``, popping — i.e. freeing — the children's
+        Schur buffers.  Returns (front, consumed CB bytes).  Children are
+        folded in tree order regardless of completion order, so the float
+        summation order (and therefore the factor bits) is identical
+        across modes."""
+        t_a0 = time.perf_counter()
         sn = self.symb.supernodes[s]
         kids = self._children[s]
         assert all(panels[c] is not None for c in kids), (
             "dispatch order violates tree precedence"
         )
         consumed = 0.0
-        kid_updates = []
-        t_a0 = time.perf_counter()
         for c in kids:
             rows_c, upd_c = updates.pop(c)
             consumed += float(rows_c.nbytes + upd_c.nbytes)
-            kid_updates.append((rows_c, upd_c))
-        f = assemble_front_np(acsc, sn, kid_updates)
+            extend_add_np(f, sn, rows_c, upd_c)
         out = f.astype(self.dtype, copy=False)
         epoch = getattr(self, "_obs_t0", None)
         if epoch is not None and obs_events.enabled():
@@ -846,36 +875,36 @@ class PlanExecutor:
         the kernel dispatch, so no lock is needed beyond the futures.
         """
         symb = self.symb
-        acsc = lower_csc(a)
         ndev = len(self.devices)
-        by_task = {t.label: t for t in self.plan.tasks if t.label >= 0}
-        if warmup:
-            # pow-2 batch sizes only; the wave schedule's sizes, which
-            # ``warmup()`` compiles, are never dispatched here
-            self._warmup_async()
-        projected_peak = self._projected_peak()
-
         n = symb.n_supernodes
         itemsize = self.dtype.itemsize
-        # plan-derived dispatch priority (earliest planned start first) and
-        # desired group size, rescaled to the executing mesh
-        prio = {
-            s: (by_task[s].start if s in by_task else 0.0, s) for s in range(n)
-        }
-        want = {
-            s: (
-                scale_group(
-                    by_task[s].devices, self.plan.total_devices, ndev
+        with stage("executor.prologue"):
+            acsc = lower_csc(a)
+            by_task = {t.label: t for t in self.plan.tasks if t.label >= 0}
+            if warmup:
+                # pow-2 batch sizes only; the wave schedule's sizes, which
+                # ``warmup()`` compiles, are never dispatched here
+                self._warmup_async()
+            projected_peak = self._projected_peak()
+            # plan-derived dispatch priority (earliest planned start
+            # first) and desired group size, rescaled to the executing mesh
+            prio = {
+                s: (by_task[s].start if s in by_task else 0.0, s)
+                for s in range(n)
+            }
+            want = {
+                s: (
+                    scale_group(
+                        by_task[s].devices, self.plan.total_devices, ndev
+                    )
+                    if s in by_task and by_task[s].devices > 0
+                    else 1
                 )
-                if s in by_task and by_task[s].devices > 0
-                else 1
+                for s in range(n)
+            }
+            n_unfinished = np.array(
+                [len(self._children[s]) for s in range(n)], dtype=np.int64
             )
-            for s in range(n)
-        }
-
-        n_unfinished = np.array(
-            [len(self._children[s]) for s in range(n)], dtype=np.int64
-        )
         updates: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         panels: List[Optional[np.ndarray]] = [None] * n
         trace: List[TraceEvent] = []
@@ -941,23 +970,25 @@ class PlanExecutor:
                 ready.append(s)
 
         def worker_small(batch, nbp, devs, delay):
-            t0 = now()
-            if delay > 0:
-                time.sleep(delay)  # the straggling device — only this
-                # dispatch's ancestors wait for it
-            out = self._run_batch(batch, nbp, devs)
-            return {"out": out, "t0": t0, "t1": now()}
+            with stage("executor.dispatch"):
+                t0 = now()
+                if delay > 0:
+                    time.sleep(delay)  # the straggling device — only this
+                    # dispatch's ancestors wait for it
+                out = self._run_batch(batch, nbp, devs)
+                return {"out": out, "t0": t0, "t1": now()}
 
         def worker_large(items, device, delay):
             # items: [(supernode, front)] — per-front panel+SYRK pipeline
-            t0 = now()
-            if delay > 0:
-                time.sleep(delay)
-            outs = [
-                self._run_large(f, symb.supernodes[s].nb, device)
-                for s, f in items
-            ]
-            return {"outs": outs, "t0": t0, "t1": now()}
+            with stage("executor.dispatch"):
+                t0 = now()
+                if delay > 0:
+                    time.sleep(delay)
+                outs = [
+                    self._run_large(f, symb.supernodes[s].nb, device)
+                    for s, f in items
+                ]
+                return {"outs": outs, "t0": t0, "t1": now()}
 
         def launch_ready(pool) -> int:
             """Issue as many dispatches as devices/memory admit; returns
@@ -1025,12 +1056,20 @@ class PlanExecutor:
                     ready.remove(s)
 
                 t_sub = now()
-                fronts = []
+                # assembly in two passes over the members: each front's
+                # own operations, and their order, are those of _assemble
+                with stage("executor.gather"):
+                    fronts = [
+                        gather_front_entries(acsc, symb.supernodes[s])
+                        for s in members
+                    ]
                 consumed = 0.0
-                for s in members:
-                    f, c = self._assemble(s, acsc, panels, updates)
-                    consumed += c
-                    fronts.append(f)
+                with stage("executor.extend_add"):
+                    for i, s in enumerate(members):
+                        fronts[i], c = self._extend_add(
+                            s, fronts[i], panels, updates
+                        )
+                        consumed += c
                 fronts_bytes = float(sum(f.nbytes for f in fronts))
                 # extend-add transient: consumed CBs coexist with the
                 # newly assembled fronts
@@ -1054,12 +1093,15 @@ class PlanExecutor:
                         delay,
                     )
                 else:
-                    batch = np.stack(
-                        [
-                            pad_front_np(f, symb.supernodes[s].nb, self.dtype)
-                            for s, f in zip(members, fronts)
-                        ]
-                    )
+                    with stage("executor.pack"):
+                        batch = np.stack(
+                            [
+                                pad_front_np(
+                                    f, symb.supernodes[s].nb, self.dtype
+                                )
+                                for s, f in zip(members, fronts)
+                            ]
+                        )
                     mem_peak = max(
                         mem_peak,
                         self._mem_panels
@@ -1145,13 +1187,16 @@ class PlanExecutor:
         pool = ThreadPoolExecutor(max_workers=workers)
         try:
             while n_done < n:
-                launched = launch_ready(pool)
+                with stage("executor.schedule"):
+                    launched = launch_ready(pool)
                 if in_flight:
-                    done, _ = futures_wait(
-                        set(in_flight), return_when=FIRST_COMPLETED
-                    )
+                    with stage("executor.wait"):
+                        done, _ = futures_wait(
+                            set(in_flight), return_when=FIRST_COMPLETED
+                        )
                     for fut in done:
-                        complete(fut)
+                        with stage("executor.unpack"):
+                            complete(fut)
                 elif not launched:
                     raise RuntimeError(
                         "async executor stalled with ready fronts"
@@ -1159,10 +1204,11 @@ class PlanExecutor:
         finally:
             pool.shutdown(wait=True)
 
-        assert all(p is not None for p in panels), "plan missed supernodes"
-        report = self._make_report(
-            trace, n_disp, mem_peak, projected_peak, "async"
-        )
+        with stage("executor.epilogue"):
+            assert all(p is not None for p in panels), "plan missed supernodes"
+            report = self._make_report(
+                trace, n_disp, mem_peak, projected_peak, "async"
+            )
         return Factorization(symb=symb, panels=panels), report  # type: ignore[arg-type]
 
 
@@ -1184,9 +1230,9 @@ class PlanExecutor:
         as **one padded vmapped dispatch** (identity lanes up to the next
         power of two, so every batch signature was pre-compiled by
         ``_warmup_async``; vmap lanes are independent, so batching never
-        changes a front's bits) and each member still assembles via
-        ``assemble_front_np`` with its children folded in tree order —
-        the bit-identity discipline of ``_assemble``, unchanged.
+        changes a front's bits) and each member still gathers its entries
+        and folds its children in tree order — the bit-identity
+        discipline of ``_assemble``, unchanged.
 
         The members run on the carved ``group`` of devices (batches
         sharded over it when sharding is on), on the first device when
@@ -1196,6 +1242,10 @@ class PlanExecutor:
         members whose parent lies outside the group), the dispatch's
         wall-clock interval, and the transient byte peak the group held.
         """
+        with stage("executor.dispatch"):
+            return self._run_group_body(gid, acsc, ext_cb, group)
+
+    def _run_group_body(self, gid, acsc, ext_cb, group) -> Dict:
         symb = self.symb
         devs = (
             self.devices[group.offset : group.offset + group.size]
@@ -1220,20 +1270,25 @@ class PlanExecutor:
         peak = held
         panels_local: Dict[int, np.ndarray] = {}
         for level in self._group_levels[gid]:
-            fronts: Dict[int, np.ndarray] = {}
+            with stage("executor.gather"):
+                fronts: Dict[int, np.ndarray] = {
+                    s: gather_front_entries(acsc, symb.supernodes[s])
+                    for s in level
+                }
             consumed = 0.0
-            for s in level:
-                sn = symb.supernodes[s]
-                kid_updates = [cb[c] for c in self._children[s]]
-                f = assemble_front_np(acsc, sn, kid_updates)
-                fronts[s] = f.astype(self.dtype, copy=False)
-                # extend-add transient: the children's CBs coexist with
-                # the assembled front until this pop
-                peak = max(peak, held + float(fronts[s].nbytes))
-                for c in self._children[s]:
-                    r, u = cb.pop(c)
-                    consumed += float(r.nbytes + u.nbytes)
-                held += float(fronts[s].nbytes)
+            with stage("executor.extend_add"):
+                for s in level:
+                    sn = symb.supernodes[s]
+                    for c in self._children[s]:
+                        extend_add_np(fronts[s], sn, *cb[c])
+                    fronts[s] = fronts[s].astype(self.dtype, copy=False)
+                    # extend-add transient: the children's CBs coexist
+                    # with the assembled front until this pop
+                    peak = max(peak, held + float(fronts[s].nbytes))
+                    for c in self._children[s]:
+                        r, u = cb.pop(c)
+                        consumed += float(r.nbytes + u.nbytes)
+                    held += float(fronts[s].nbytes)
             peak = max(peak, held)
             held -= consumed
 
@@ -1256,30 +1311,34 @@ class PlanExecutor:
                     continue
                 for lo in range(0, len(sns), self.max_batch):
                     chunk = sns[lo : lo + self.max_batch]
-                    batch = np.stack(
-                        [
-                            pad_front_np(
-                                fronts[s], symb.supernodes[s].nb, self.dtype
-                            )
-                            for s in chunk
-                        ]
-                    )
                     k = len(chunk)
                     kp = _pow2_ceil(k)
-                    if kp > k:  # identity lanes: exact no-ops, and the
-                        # pow-2 signature is what warmup compiled
-                        eye = np.broadcast_to(
-                            np.eye(mp, dtype=self.dtype), (kp - k, mp, mp)
+                    with stage("executor.pack"):
+                        batch = np.stack(
+                            [
+                                pad_front_np(
+                                    fronts[s],
+                                    symb.supernodes[s].nb,
+                                    self.dtype,
+                                )
+                                for s in chunk
+                            ]
                         )
-                        batch = np.concatenate([batch, eye], axis=0)
+                        if kp > k:  # identity lanes: exact no-ops, and
+                            # the pow-2 signature is what warmup compiled
+                            eye = np.broadcast_to(
+                                np.eye(mp, dtype=self.dtype), (kp - k, mp, mp)
+                            )
+                            batch = np.concatenate([batch, eye], axis=0)
                     peak = max(peak, held + float(batch.nbytes))
                     out = self._run_batch(batch, nbp, devs)
-                    for s, o in zip(chunk, out[:k]):
-                        sn = symb.supernodes[s]
-                        panel, schur = extract_panel_schur(o, sn.m, sn.nb)
-                        panels_local[s] = panel
-                        if sn.m > sn.nb:
-                            cb[s] = (sn.rows[sn.nb :], schur)
+                    with stage("executor.unpack"):
+                        for s, o in zip(chunk, out[:k]):
+                            sn = symb.supernodes[s]
+                            panel, schur = extract_panel_schur(o, sn.m, sn.nb)
+                            panels_local[s] = panel
+                            if sn.m > sn.nb:
+                                cb[s] = (sn.rows[sn.nb :], schur)
             for s in level:
                 sn = symb.supernodes[s]
                 held += float(panels_local[s].nbytes)
@@ -1408,33 +1467,33 @@ class PlanExecutor:
         optimizer already chose the batches.
         """
         symb = self.symb
-        acsc = lower_csc(a)
         ndev = len(self.devices)
-        by_task = {t.label: t for t in self.plan.tasks if t.label >= 0}
-        if warmup:
-            self._warmup_async()
-        projected_peak = self._projected_peak()
-
         ng = len(self._groups)
         itemsize = self.dtype.itemsize
-        prio = {
-            g: (by_task[g].start if g in by_task else 0.0, g)
-            for g in range(ng)
-        }
-        want = {
-            g: (
-                scale_group(
-                    by_task[g].devices, self.plan.total_devices, ndev
+        with stage("executor.prologue"):
+            acsc = lower_csc(a)
+            by_task = {t.label: t for t in self.plan.tasks if t.label >= 0}
+            if warmup:
+                self._warmup_async()
+            projected_peak = self._projected_peak()
+            prio = {
+                g: (by_task[g].start if g in by_task else 0.0, g)
+                for g in range(ng)
+            }
+            want = {
+                g: (
+                    scale_group(
+                        by_task[g].devices, self.plan.total_devices, ndev
+                    )
+                    if g in by_task and by_task[g].devices > 0
+                    else 1
                 )
-                if g in by_task and by_task[g].devices > 0
-                else 1
+                for g in range(ng)
+            }
+            n_unfinished = np.array(
+                [len(self._group_ext_children[g]) for g in range(ng)],
+                dtype=np.int64,
             )
-            for g in range(ng)
-        }
-        n_unfinished = np.array(
-            [len(self._group_ext_children[g]) for g in range(ng)],
-            dtype=np.int64,
-        )
         updates: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         panels: List[Optional[np.ndarray]] = [None] * symb.n_supernodes
         trace: List[TraceEvent] = []
@@ -1592,13 +1651,16 @@ class PlanExecutor:
         pool = ThreadPoolExecutor(max_workers=workers)
         try:
             while n_done < ng:
-                launched = launch_ready(pool)
+                with stage("executor.schedule"):
+                    launched = launch_ready(pool)
                 if in_flight:
-                    done, _ = futures_wait(
-                        set(in_flight), return_when=FIRST_COMPLETED
-                    )
+                    with stage("executor.wait"):
+                        done, _ = futures_wait(
+                            set(in_flight), return_when=FIRST_COMPLETED
+                        )
                     for fut in done:
-                        complete(fut)
+                        with stage("executor.unpack"):
+                            complete(fut)
                 elif not launched and n_done < ng:
                     # remaining groups are label -1 placeholders with no
                     # computation (e.g. a lone virtual root)
@@ -1619,10 +1681,11 @@ class PlanExecutor:
         finally:
             pool.shutdown(wait=True)
 
-        assert all(p is not None for p in panels), "plan missed supernodes"
-        report = self._make_report(
-            trace, n_disp, mem_peak, projected_peak, "async"
-        )
+        with stage("executor.epilogue"):
+            assert all(p is not None for p in panels), "plan missed supernodes"
+            report = self._make_report(
+                trace, n_disp, mem_peak, projected_peak, "async"
+            )
         return Factorization(symb=symb, panels=panels), report  # type: ignore[arg-type]
 
 

@@ -137,6 +137,35 @@ def test_kernel_matmuls_contract_at_full_f32_precision(kernel, shapes):
         assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" in params
 
 
+def _pallas_call_names(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                out += _pallas_call_names(sub)
+    return out
+
+
+@pytest.mark.parametrize(
+    "fn,shape,names",
+    [
+        (lambda x: ops.batched_front_factor(x, 128, True), (2, 256, 256),
+         {"front_factor_vmem"}),
+        (lambda x: ops.partial_cholesky(x, 600, interpret=True), (1200, 1200),
+         {"panel_factor", "syrk_downdate"}),
+    ],
+    ids=["batched_front_factor", "partial_cholesky"],
+)
+def test_pallas_calls_carry_stable_kernel_names(fn, shape, names):
+    """Each Pallas call is named, so a device trace can match kernels
+    by name and not by the jitted function that launched them."""
+    jaxpr = jax.make_jaxpr(fn)(jnp.zeros(shape, jnp.float32))
+    assert set(_pallas_call_names(jaxpr.jaxpr)) == names
+
+
 # ----------------------------------------------------------------------
 # flash attention kernel (§Perf fix for the dense-train cells)
 # ----------------------------------------------------------------------

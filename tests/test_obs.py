@@ -38,10 +38,12 @@ ALPHA = 0.9
 
 @pytest.fixture(autouse=True)
 def fresh_obs():
+    """The bus is off by default; every test here records with it on,
+    and leaves the default behind."""
     obs.enable()
     obs.reset()
     yield
-    obs.enable()
+    obs.disable()
     obs.reset()
 
 

@@ -101,6 +101,27 @@ def test_large_front_pipeline_compiles(one_chip):
     assert len(_custom_calls(c)) == 2  # one panel, one trailing SYRK
 
 
+@pytest.mark.parametrize(
+    "fn,shape,names",
+    [
+        (lambda f: ops._batched_front_factor(f, 256, False), (8, 512, 512),
+         ["front_factor_vmem"]),
+        (lambda f: ops._partial_cholesky_impl(f, 256, False), (1280, 1280),
+         ["panel_factor", "syrk_downdate"]),
+    ],
+    ids=["batched_front_factor", "partial_cholesky"],
+)
+def test_kernel_names_reach_the_compiled_custom_calls(one_chip, fn, shape, names):
+    """The HLO name of each Pallas custom call (what a device trace's
+    ``XLA Ops`` line shows) carries the kernel's ``name=``:
+    ``%vmap_front_factor_vmem_.1``, ``%panel_factor.2``, ..."""
+    c = _compile(fn, shape, sharding=one_chip)
+    ops_ = [ln.split(" = ", 1)[0].split()[-1] for ln in _custom_calls(c)]
+    assert len(ops_) == len(names)
+    for op, name in zip(ops_, names):
+        assert name in op, (op, name)
+
+
 def test_sharded_batch_runs_one_kernel_per_device_without_gather(topo):
     """The executor's sharded dispatch on a 4-chip mesh: each device runs
     the kernel on its own quarter of the batch, and nothing is gathered
