@@ -58,6 +58,7 @@ use).
 """
 from __future__ import annotations
 
+import heapq
 import math
 import time
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor
@@ -307,6 +308,58 @@ class _Inflight:
     held_bytes: float  # buffers the worker holds until completion
     t_submit: float
     large: bool  # per-front partial_cholesky path
+
+
+class ReadyQueue:
+    """The async runner's ready fronts: one binary heap of ``(prio[s], s)``
+    per padded shape class, so a dispatch costs O(classes + batch · log)
+    rather than a regrouping of every ready front.
+
+    ``pop_batch`` takes the class whose highest-priority front (smallest
+    ``prio``) leads all ready fronts, and from it the first
+    ``pow2_floor(min(len, max_batch))`` fronts in priority order — one
+    front when the class is past ``VMEM_FRONT_MAX`` (the per-front large
+    path).  ``prio`` must be a total order; the choice then depends only on
+    which fronts are ready, so fronts handed back with ``push`` (shed under
+    a memory cap, or a dispatch that could not launch) leave it unchanged.
+    """
+
+    def __init__(
+        self,
+        shape: Sequence[Tuple[int, int]],
+        prio: Dict[int, Tuple[float, int]],
+        max_batch: int,
+    ) -> None:
+        self._shape = shape  # front id -> (mp, nbp) padded shape class
+        self._prio = prio
+        self._max_batch = max_batch
+        self._heaps: Dict[Tuple[int, int], List] = {}  # non-empty only
+        self._n = 0
+
+    def __len__(self) -> int:
+        return self._n
+
+    def push(self, s: int) -> None:
+        heap = self._heaps.setdefault(self._shape[s], [])
+        heapq.heappush(heap, (self._prio[s], s))
+        self._n += 1
+
+    def pop_batch(self) -> Tuple[Tuple[int, int], List[int]]:
+        """(shape class, members in priority order) of the next dispatch."""
+        key = min(self._heaps, key=lambda k: self._heaps[k][0])
+        heap = self._heaps[key]
+        if key[0] > VMEM_FRONT_MAX:
+            k = 1
+        else:
+            # power-of-two batch sizes only: bounds the jit signature
+            # space to what _warmup_async pre-compiled (the remainder
+            # stays ready for the next dispatch)
+            k = pow2_floor(min(len(heap), self._max_batch))
+        members = [heapq.heappop(heap)[1] for _ in range(k)]
+        if not heap:
+            del self._heaps[key]
+        self._n -= k
+        return key, members
 
 
 class PlanExecutor:
@@ -905,13 +958,17 @@ class PlanExecutor:
             n_unfinished = np.array(
                 [len(self._children[s]) for s in range(n)], dtype=np.int64
             )
+            ready = ReadyQueue(
+                [padded_shape(sn.m, sn.nb) for sn in symb.supernodes],
+                prio,
+                self.max_batch,
+            )
         updates: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         panels: List[Optional[np.ndarray]] = [None] * n
         trace: List[TraceEvent] = []
         alloc = BuddyAllocator(ndev)
         in_flight: Dict = {}  # Future -> _Inflight
         t_ready: Dict[int, float] = {}
-        ready: List[int] = []
         self._mem_panels = 0.0
         self._mem_updates = 0.0
         mem_inflight = 0.0
@@ -967,7 +1024,7 @@ class PlanExecutor:
         for s in range(n):
             if n_unfinished[s] == 0:
                 t_ready[s] = 0.0
-                ready.append(s)
+                ready.push(s)
 
         def worker_small(batch, nbp, devs, delay):
             with stage("executor.dispatch"):
@@ -998,24 +1055,8 @@ class PlanExecutor:
             while ready:
                 if alloc.n_free == 0:
                     break
-                classes: Dict[Tuple[int, int], List[int]] = {}
-                for s in ready:
-                    sn = symb.supernodes[s]
-                    classes.setdefault(padded_shape(sn.m, sn.nb), []).append(s)
-                key = min(
-                    classes, key=lambda k: min(prio[s] for s in classes[k])
-                )
+                key, members = ready.pop_batch()
                 mp, nbp = key
-                members = sorted(classes[key], key=lambda s: prio[s])
-                if mp > VMEM_FRONT_MAX:
-                    members = members[:1]
-                else:
-                    # power-of-two batch sizes only: bounds the jit
-                    # signature space to what _warmup_async pre-compiled
-                    # (the remainder stays ready for the next dispatch)
-                    members = members[
-                        : pow2_floor(min(len(members), self.max_batch))
-                    ]
 
                 def dispatch_bytes(ms) -> float:
                     fb = sum(
@@ -1033,9 +1074,11 @@ class PlanExecutor:
                         and resident + dispatch_bytes(members)
                         > self.memory_cap_bytes
                     ):
-                        members = members[:-1]  # shed the lowest priority
+                        ready.push(members.pop())  # shed the lowest priority
                     if resident + dispatch_bytes(members) > self.memory_cap_bytes:
                         if in_flight or launched:
+                            for s in members:
+                                ready.push(s)
                             break  # wait for buffers to free
                         # pipeline empty: dispatch anyway (progress beats
                         # the cap, same as the wave path's single dispatch)
@@ -1047,13 +1090,13 @@ class PlanExecutor:
                         break
                     groups[s] = g
                 if not groups:
+                    for s in members:
+                        ready.push(s)
                     break  # no free device — wait for a completion
                 # every chosen member joins the dispatch: the batch is one
                 # kernel launch sharded over the carved groups' union, so
                 # fronts beyond the free capacity time-share it (same
                 # discipline as the wave carver's oversubscription rule)
-                for s in members:
-                    ready.remove(s)
 
                 t_sub = now()
                 # assembly in two passes over the members: each front's
@@ -1179,7 +1222,7 @@ class PlanExecutor:
                     n_unfinished[p] -= 1
                     if n_unfinished[p] == 0:
                         t_ready[p] = t1
-                        ready.append(p)
+                        ready.push(p)
             n_done += len(info.supernodes)
             publish_state()
 

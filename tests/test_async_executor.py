@@ -14,9 +14,12 @@ import sys
 import jax
 import numpy as np
 import pytest
+import scipy.sparse as sp
 
-from repro.distributed.device_groups import BuddyAllocator
-from repro.runtime.executor import MODES, PlanExecutor
+from repro.distributed.device_groups import BuddyAllocator, pow2_floor
+from repro.kernels.frontal_cholesky import VMEM_FRONT_MAX
+from repro.runtime import executor as executor_mod
+from repro.runtime.executor import MODES, PlanExecutor, ReadyQueue
 from repro.runtime.straggler import FrontDelays
 from repro.sparse import (
     analyze,
@@ -25,6 +28,8 @@ from repro.sparse import (
     nested_dissection_2d,
     permute_symmetric,
 )
+from repro.sparse.matrix import random_spd
+from repro.sparse.ordering import min_degree
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -367,3 +372,225 @@ print("ASYNC_AB_OK", round(speedup, 3))
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "ASYNC_AB_OK" in out.stdout
+
+
+# ----------------------------------------------------------------------
+# ReadyQueue: per-shape-class heaps in place of the regrouped ready list
+# ----------------------------------------------------------------------
+class _RegroupingReadyList:
+    """The async runner's ready set before the per-class heaps, kept as it
+    was: a flat list regrouped by padded shape class at every dispatch.
+    The reference the heaps must match, dispatch for dispatch."""
+
+    def __init__(self, shape, prio, max_batch):
+        self.shape, self.prio, self.max_batch = shape, prio, max_batch
+        self.ready = []
+
+    def __len__(self):
+        return len(self.ready)
+
+    def push(self, s):
+        self.ready.append(s)
+
+    def pop_batch(self):
+        ready, prio = self.ready, self.prio
+        classes = {}
+        for s in ready:
+            classes.setdefault(self.shape[s], []).append(s)
+        key = min(classes, key=lambda k: min(prio[s] for s in classes[k]))
+        mp, nbp = key
+        members = sorted(classes[key], key=lambda s: prio[s])
+        if mp > VMEM_FRONT_MAX:
+            members = members[:1]
+        else:
+            members = members[: pow2_floor(min(len(members), self.max_batch))]
+        for s in members:
+            ready.remove(s)
+        return key, members
+
+
+def _queue(shapes, starts, max_batch=32):
+    """A ReadyQueue over fronts 0.. with the given classes and start times
+    (priority ``(start, s)``, as the runner builds it)."""
+    prio = {s: (float(t), s) for s, t in enumerate(starts)}
+    return ReadyQueue(shapes, prio, max_batch)
+
+
+def test_ready_queue_pops_class_of_earliest_front():
+    a, b = (256, 128), (384, 128)
+    q = _queue([a, b, a, b, a], [5, 1, 2, 3, 4])
+    for s in range(5):
+        q.push(s)
+    assert q.pop_batch() == (b, [1, 3])  # front 1 leads: its class goes
+    assert q.pop_batch() == (a, [2, 4])  # pow2 cut of 3: 2, 4 before 0
+    assert q.pop_batch() == (a, [0])
+    assert len(q) == 0
+
+
+def test_ready_queue_pow2_cut_and_max_batch():
+    shape = (256, 128)
+    q = _queue([shape] * 13, range(13), max_batch=4)
+    for s in reversed(range(13)):
+        q.push(s)
+    sizes = []
+    while q:
+        key, members = q.pop_batch()
+        assert key == shape and members == sorted(members)
+        sizes.append(len(members))
+    assert sizes == [4, 4, 4, 1]
+    q = _queue([shape] * 13, range(13))
+    for s in range(13):
+        q.push(s)
+    assert [len(q.pop_batch()[1]) for _ in range(3)] == [8, 4, 1]
+
+
+def test_ready_queue_one_front_past_vmem():
+    big = (VMEM_FRONT_MAX + 128, 128)
+    q = _queue([big] * 3, [2, 0, 1])
+    for s in range(3):
+        q.push(s)
+    assert [q.pop_batch() for _ in range(3)] == [(big, [1]), (big, [2]), (big, [0])]
+
+
+def test_ready_queue_push_back_and_count():
+    a, b = (256, 128), (128, 128)
+    q = _queue([a] * 6 + [b], [0, 1, 2, 3, 4, 5, 9])
+    for s in range(7):
+        q.push(s)
+    assert len(q) == 7
+    key, members = q.pop_batch()
+    assert (key, members) == (a, [0, 1, 2, 3])
+    assert len(q) == 3
+    q.push(members.pop())  # shed the lowest priority under a memory cap
+    q.push(members.pop())
+    assert len(q) == 5
+    # the shed fronts lead again, ahead of the rest of their class
+    assert q.pop_batch() == (a, [2, 3, 4, 5])
+    for s in members:  # a dispatch that could not launch hands all back
+        q.push(s)
+    assert q.pop_batch() == (a, [0, 1])
+    assert q.pop_batch() == (b, [6])
+    assert len(q) == 0 and not q
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_ready_queue_replays_regrouping(seed):
+    """Random forests and classes (some past ``VMEM_FRONT_MAX``), ties in
+    planned start, several dispatches in flight completing out of order,
+    shedding and hand-backs: the heaps choose what the regrouping chose."""
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(50, 400))
+    parent = [
+        int(rng.integers(s + 1, n)) if s < n - 1 and rng.random() < 0.95 else -1
+        for s in range(n)
+    ]
+    classes = [(256, 128), (384, 128), (256, 256), (128, 128),
+               (VMEM_FRONT_MAX + 128, 128), (VMEM_FRONT_MAX + 512, 256)]
+    pick = rng.integers(0, len(classes), n)
+    shapes = [classes[i] for i in pick]
+    starts = rng.integers(0, n // 4, n)  # ties broken by the front id
+    max_batch = int(rng.choice([1, 2, 5, 32]))
+    ndev = int(rng.choice([1, 2, 4]))
+    prio = {s: (float(starts[s]), s) for s in range(n)}
+
+    def replay(queue_cls):
+        r = np.random.default_rng(seed + 1000)
+        q = queue_cls(shapes, prio, max_batch)
+        waiting = [0] * n
+        for p in parent:
+            if p >= 0:
+                waiting[p] += 1
+        for s in range(n):
+            if waiting[s] == 0:
+                q.push(s)
+        in_flight, seq, dispatched = [], [], set()
+        while len(dispatched) < n or in_flight:
+            while q and len(in_flight) < ndev:
+                key, members = q.pop_batch()
+                cap = int(r.integers(1, 6))
+                while len(members) > cap:
+                    q.push(members.pop())
+                if in_flight and r.random() < 0.2:
+                    for s in members:
+                        q.push(s)
+                    break
+                in_flight.append(members)
+                dispatched.update(members)
+                seq.append((key, tuple(members)))
+            for s in in_flight.pop(int(r.integers(0, len(in_flight)))):
+                p = parent[s]
+                if p >= 0:
+                    waiting[p] -= 1
+                    if waiting[p] == 0:
+                        q.push(p)
+            assert len(q) == sum(
+                1 for s in range(n) if waiting[s] == 0 and s not in dispatched
+            )
+        return seq
+
+    assert replay(ReadyQueue) == replay(_RegroupingReadyList)
+
+
+@pytest.fixture(scope="module")
+def mixed_problem():
+    """A forest with three padded shape classes: a random sparse SPD block
+    (min-degree order; (256, 128) fronts and (128, 128) roots) beside a
+    dense 130 x 130 block (one (256, 256) front)."""
+    rng = np.random.default_rng(0)
+    r = random_spd(120, 4.0, rng)
+    r = permute_symmetric(r, min_degree(r))
+    d = rng.uniform(-1.0, 1.0, (130, 130))
+    d = d @ d.T + 130.0 * np.eye(130)
+    ap = sp.block_diag([r, sp.csr_matrix(d)]).tocsr()
+    symb = analyze(ap, relax=1)
+    plan = make_plan(symb.task_tree(), 8, alpha=0.9)
+    return ap, symb, plan
+
+
+def _dispatches(report):
+    """Per-dispatch member tuples, in dispatch order."""
+    by_seq = {}
+    for e in report.trace:
+        by_seq.setdefault(e.wave, []).append(e.front)
+    return [tuple(by_seq[k]) for k in sorted(by_seq)]
+
+
+@pytest.mark.parametrize(
+    "case, kw",
+    [
+        ("classes", {}),
+        ("max_batch", {"max_batch": 2}),
+        ("memory_cap", {"memory_cap_bytes": 3.0e6}),
+    ],
+)
+def test_ready_queue_dispatches_as_regrouping(mixed_problem, monkeypatch, case, kw):
+    """The executor with the heaps issues the dispatches, and returns the
+    panels, of the executor with the regrouped ready list.  One device:
+    one dispatch in flight, so the completion order, and with it the
+    dispatch sequence, is deterministic."""
+    ap, symb, plan = mixed_problem
+    shapes = {
+        executor_mod.padded_shape(sn.m, sn.nb) for sn in symb.supernodes
+    }
+    assert len(shapes) == 3
+
+    def run():
+        ex = PlanExecutor(
+            symb, plan, devices=jax.devices()[:1], mode="async", **kw
+        )
+        return ex.run(ap, warmup=False)
+
+    fq, rq = run()
+    monkeypatch.setattr(executor_mod, "ReadyQueue", _RegroupingReadyList)
+    fr, rr = run()
+    got, want = _dispatches(rq), _dispatches(rr)
+    assert got == want
+    for pq, pr in zip(fq.panels, fr.panels):
+        np.testing.assert_array_equal(pq, pr)
+    sizes = [len(m) for m in got]
+    if case == "max_batch":
+        # a class wider than the cap leaves its remainder ready
+        assert max(sizes) == 2 and sizes.count(2) > 1
+    if case == "memory_cap":
+        # shedding leaves batches the pow2 cut alone never makes
+        assert any(k != pow2_floor(k) for k in sizes)
