@@ -15,7 +15,7 @@ transplanted to the wall clock of a real cluster:
   and per-task ratios from :func:`repro.core.pm.tree_pm_ratios`.  The
   resulting fractions order dispatch and size slot grants;
 * ready fronts are grouped by padded shape class **across tenants**
-  (continuous batching) and dispatched to workers as single vmapped
+  (continuous batching) and dispatched to workers as single batched
   front groups;
 * a lost heartbeat is a Theorem-6 capacity event: the dead worker's
   in-flight batches are tombstoned and requeued, the survivors'
@@ -193,7 +193,7 @@ class ClusterScheduler:
         capacity-down event).
     ``batching`` / ``max_batch``
         cross-tenant continuous batching of same-shape ready fronts
-        into one vmapped dispatch (``False`` → one front per dispatch).
+        into one batched dispatch (``False`` → one front per dispatch).
     ``work_rate``
         simulated work units per second at share 1 — only simulated
         (matrix-free) trees consume it.
@@ -665,7 +665,7 @@ class ClusterScheduler:
             stack = np.stack(
                 [self.trees[t].assemble_padded(i) for _, _, t, i in taken]
             )
-            msg_extra = {"fronts": stack, "nbp": int(key[2])}
+            msg_extra = {"fronts": stack}
         elif key[0] == "large":
             kind = "large"
             (_, _, t, i) = taken[0]

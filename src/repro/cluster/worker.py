@@ -15,8 +15,8 @@ protocol), registers its slot capacity, and then serves two loops:
 Three dispatch kinds mirror the async executor's numeric path:
 
 ``batched``
-    a (B, mp, mp) stack of padded fronts — one vmapped
-    ``batched_front_factor`` call, then per-lane
+    a (B, mp, mp) stack of padded fronts — one
+    ``batched_front_factor`` call with each lane's pivot count, then per-lane
     ``extract_panel_schur`` host-side; lanes are independent, so batch
     composition (including *cross-tenant* composition) cannot change
     bits.
@@ -186,15 +186,16 @@ class Worker:
         )
 
     def _run_batched(self, msg: dict) -> list:
-        """One vmapped kernel over the padded stack, per-lane extraction."""
+        """One kernel call over the padded stack, per-lane extraction."""
         import jax.numpy as jnp
 
         from repro.kernels.ops import batched_front_factor, extract_panel_schur
 
         fronts = np.asarray(msg["fronts"])
+        nb = np.array([it["nb"] for it in msg["items"]], np.int32)
         out = np.asarray(
             batched_front_factor(
-                jnp.asarray(fronts), int(msg["nbp"]), self.interpret
+                jnp.asarray(fronts), nb, self.interpret
             ).block_until_ready()
         )
         if self.dispatch_overhead_s:

@@ -6,19 +6,20 @@ VMEM in MXU-aligned 128-tiles:
 
 * ``front_factor_vmem`` — whole-front-in-VMEM partial factorization for
   fronts up to ``VMEM_FRONT_MAX`` (the common case: the vast majority of
-  assembly-tree fronts).  Inner loop: per-128-column block, unblocked
-  rank-1 panel factorization (VPU work, O(m·tb) per block) followed by one
-  MXU matmul Schur downdate of the trailing columns — the O(m²·tb) flops
-  land on the MXU.
+  assembly-tree fronts), one front per grid step of a batched call, each
+  with its own pivot count (scalar-prefetched into SMEM).  Inner loop:
+  per-128-column block, unblocked rank-1 factorization of the block's
+  pivot columns only (VPU work) followed by one MXU matmul Schur downdate
+  of the trailing columns — the O(m²·tb) flops land on the MXU.
 * ``panel_factor`` — (M, NB) slab factorization for the large-front path
   (ops.py loops panels and applies the tiled SYRK between them).
 * ``syrk_downdate`` — grid-tiled C −= A·Aᵀ trailing update; C tiles stream
   through VMEM, the two A slabs are fetched per tile.
 
 Masking convention: fronts are symmetric and only the lower triangle is
-kept correct.  Padding: ops.py pads fronts with a unit diagonal so padded
-pivot columns factor to no-ops (L column = e_j, zero Schur contribution),
-keeping every kernel shape a static multiple of 128.
+kept correct.  Padding: ops.py pads fronts with a unit diagonal, so every
+kernel shape is a static multiple of 128; padded rows and columns are
+inert (a padded pivot column factors to e_j with zero Schur contribution).
 
 Multiplier-extraction trick: the rank-1 update of column c by the freshly
 factored column ℓ needs the scalar ℓ[c] (a gather along rows).  Gathers are
@@ -46,13 +47,15 @@ _FRONT_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2**20)
 _MXU_PRECISION = jax.lax.Precision.HIGHEST
 
 
-def _factor_block_columns(a, off, tb, mp, ncols):
-    """Unblocked Cholesky of columns [off, off+tb) of an (mp, ncols) slab
-    whose row i aligns with column i (diagonal at [i, i]).
+def _factor_block_columns(a, off, n, tb, mp, ncols):
+    """Unblocked Cholesky of columns [off, off+n) of an (mp, ncols) slab
+    whose row i aligns with column i (diagonal at [i, i]); ``n`` ≤ ``tb``
+    may be traced.
 
     Returns the slab with those columns replaced by L columns and the
-    remaining columns of the *block* rank-1-downdated.  Columns right of the
-    block are untouched (the caller applies the MXU block downdate).
+    remaining columns of the *block* [off, off+tb) rank-1-downdated.
+    Columns right of the block are untouched (the caller applies the MXU
+    block downdate).
     """
     rows = jax.lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, ncols), 1)
@@ -77,22 +80,28 @@ def _factor_block_columns(a, off, tb, mp, ncols):
         upd = l_below * jnp.where(in_block, mult, 0.0)
         return jnp.where(is_col, lcol, a - upd).astype(a.dtype)
 
-    return jax.lax.fori_loop(0, tb, col_step, a)
+    return jax.lax.fori_loop(0, n, col_step, a)
 
 
 # ----------------------------------------------------------------------
 # Whole-front VMEM-resident kernel
 # ----------------------------------------------------------------------
-def _front_factor_body(front_ref, out_ref, *, mp: int, nbp: int, tb: int):
+def _front_factor_body(nb_ref, front_ref, out_ref, *, mp: int, tb: int):
+    # this lane's pivot count: columns [0, nb) are eliminated, the border
+    # [nb, m) takes the Schur update, and the unit diagonal past m is inert
+    nb = nb_ref[pl.program_id(0)]
     a = front_ref[...]
     rows = jax.lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, mp), 1)
 
     def block_step(kb, a):
         off = kb * tb
-        a = _factor_block_columns(a, off, tb, mp, mp)
-        # MXU Schur downdate of all columns right of the block
-        blockmask = (cols >= off) & (cols < off + tb)
+        a = _factor_block_columns(a, off, jnp.minimum(tb, nb - off), tb, mp, mp)
+        if mp == tb:
+            return a  # one block: no columns right of it
+        # MXU Schur downdate of all columns right of the block, by the
+        # block's pivot columns only
+        blockmask = (cols >= off) & (cols < jnp.minimum(off + tb, nb))
         panel = jnp.where(blockmask & (rows > cols), a, 0.0)  # (mp, mp)
         upd = jax.lax.dot_general(
             panel, panel, (((1,), (1,)), ((), ())),
@@ -102,29 +111,39 @@ def _front_factor_body(front_ref, out_ref, *, mp: int, nbp: int, tb: int):
         trailing = cols >= off + tb
         return jnp.where(trailing, a - upd, a)
 
-    a = jax.lax.fori_loop(0, nbp // tb, block_step, a)
+    a = jax.lax.fori_loop(0, (nb + tb - 1) // tb, block_step, a)
     out_ref[...] = a
 
 
 def front_factor_vmem(
-    front: jax.Array, nbp: int, interpret: bool = False
+    fronts: jax.Array, nb, interpret: bool = False
 ) -> jax.Array:
-    """Factor the leading ``nbp`` (multiple-of-128) columns of a padded
-    (mp, mp) front in one VMEM-resident pallas_call.  Returns the updated
-    matrix: factor panel in the first nbp columns (lower triangle), Schur
+    """Partial factorization of padded fronts, one VMEM-resident front per
+    grid step.  ``fronts`` is a (B, mp, mp) stack (or one (mp, mp) front)
+    with mp a multiple of 128; ``nb`` gives each lane's pivot count, (B,)
+    int32 (or one int for every lane), and reaches the kernel through
+    scalar prefetch.  Lane b eliminates exactly its columns [0, nb[b]):
+    the factor panel lands in those columns (lower triangle), the Schur
     complement in the trailing block."""
-    mp = front.shape[0]
-    assert front.shape == (mp, mp) and mp % TILE == 0 and nbp % TILE == 0
-    body = functools.partial(_front_factor_body, mp=mp, nbp=nbp, tb=TILE)
-    return pl.pallas_call(
+    single = fronts.ndim == 2
+    if single:
+        fronts = fronts[None]
+    b, mp, _ = fronts.shape
+    assert fronts.shape == (b, mp, mp) and mp % TILE == 0
+    nb = jnp.broadcast_to(jnp.asarray(nb, jnp.int32), (b,))
+    body = functools.partial(_front_factor_body, mp=mp, tb=TILE)
+    lane = pl.BlockSpec((None, mp, mp), lambda i, nb_ref: (i, 0, 0))
+    out = pl.pallas_call(
         body,
-        out_shape=jax.ShapeDtypeStruct((mp, mp), front.dtype),
-        in_specs=[pl.BlockSpec((mp, mp), lambda: (0, 0))],
-        out_specs=pl.BlockSpec((mp, mp), lambda: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, mp, mp), fronts.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b,), in_specs=[lane], out_specs=lane
+        ),
         compiler_params=_FRONT_PARAMS,
         interpret=interpret,
         name="front_factor_vmem",
-    )(front)
+    )(nb, fronts)
+    return out[0] if single else out
 
 
 # ----------------------------------------------------------------------
@@ -137,7 +156,7 @@ def _panel_factor_body(slab_ref, out_ref, *, mp: int, nb: int, tb: int):
 
     def block_step(kb, a):
         off = kb * tb
-        a = _factor_block_columns(a, off, tb, mp, nb)
+        a = _factor_block_columns(a, off, tb, tb, mp, nb)
         # MXU downdate of the slab columns right of the block:
         # upd[r, c] = Σ_k panel[r, k]·panel[c, k]; rows c of the panel are
         # its leading nb rows (row i ↔ column i alignment).

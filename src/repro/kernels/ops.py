@@ -63,7 +63,7 @@ def _partial_cholesky_impl(
         f = f.at[nbp : nbp + mb, nbp : nbp + mb].set(front[nb:, nb:])
 
     if mp <= VMEM_FRONT_MAX:
-        out = front_factor_vmem(f, nbp, interpret=interpret)
+        out = front_factor_vmem(f, nb, interpret=interpret)
     else:
         out = f
         for k in range(0, nbp, OUTER_PANEL):
@@ -114,39 +114,31 @@ def factor_fn(interpret: Optional[bool] = None):
 
 
 # ----------------------------------------------------------------------
-# Batched wave dispatch (the plan executor's path).
+# Batched dispatch (the plan executor's path).
 #
-# Fronts of one wave are padded host-side to a common 128-aligned (mp, mp)
-# shape class and factored in ONE vmapped pallas_call — one dispatch per
-# shape class per wave instead of one per front.  Padding follows the same
-# unit-diagonal convention as ``_partial_cholesky_impl``: padded pivot
-# columns factor to e_j no-ops, so fronts with different true (m, nb) can
-# share a class as long as they round to the same (mp, nbp).
+# Fronts are padded host-side to a 128-aligned (mp, mp) stack and factored
+# in ONE pallas_call with a grid over the stack — one dispatch per shape
+# class instead of one per front.  The layout is tight: the true front
+# fills [0, m)², pivots first, and everything past m is a unit diagonal.
+# Each lane's pivot count reaches the kernel as an operand, so fronts with
+# different true (m, nb) share a class as long as they round to the same
+# (mp, nbp).
 # ----------------------------------------------------------------------
 def padded_shape(m: int, nb: int) -> Tuple[int, int]:
-    """(mp, nbp): the 128-aligned padded front order and pivot width."""
-    mb = m - nb
-    nbp = _round_up(max(nb, 1), TILE)
-    mbp = _round_up(mb, TILE) if mb > 0 else 0
-    return nbp + mbp, nbp
+    """(mp, nbp): the shape class of an (m, m) front with nb pivots — its
+    order and its pivot count, each rounded up to a multiple of 128."""
+    return _round_up(m, TILE), _round_up(max(nb, 1), TILE)
 
 
 def pad_front_np(front: np.ndarray, nb: int, dtype=None) -> np.ndarray:
-    """Host-side padding of an (m, m) front to its (mp, mp) shape class.
-
-    Pivots land in [0, nb), the border in [nbp, nbp+mb); everything else is
-    a unit diagonal.  Mirrors the in-jit padding of _partial_cholesky_impl
-    so the two paths are interchangeable.
-    """
+    """Host-side padding of an (m, m) front to its (mp, mp) shape class:
+    the front at [0, m)², a unit diagonal past it."""
     m = front.shape[0]
-    mb = m - nb
-    mp, nbp = padded_shape(m, nb)
-    f = np.eye(mp, dtype=dtype or front.dtype)
-    f[:nb, :nb] = front[:nb, :nb]
-    if mb > 0:
-        f[nbp : nbp + mb, :nb] = front[nb:, :nb]
-        f[:nb, nbp : nbp + mb] = front[:nb, nb:]
-        f[nbp : nbp + mb, nbp : nbp + mb] = front[nb:, nb:]
+    mp, _ = padded_shape(m, nb)
+    f = np.zeros((mp, mp), dtype=dtype or front.dtype)
+    f[:m, :m] = front
+    i = np.arange(m, mp)
+    f[i, i] = 1
     return f
 
 
@@ -158,49 +150,47 @@ def extract_panel_schur(
     Host-side analogue of the output gather in _partial_cholesky_impl:
     zero the garbage above L11's diagonal, symmetrize the Schur block.
     """
-    mb = m - nb
-    _, nbp = padded_shape(m, nb)
-    top = np.tril(out[:nb, :nb])
-    if mb > 0:
-        panel = np.concatenate([top, out[nbp : nbp + mb, :nb]], axis=0)
-        low = np.tril(out[nbp : nbp + mb, nbp : nbp + mb])
-        schur = low + low.T - np.diag(np.diag(low))
-    else:
-        panel = top
-        schur = np.zeros((0, 0), dtype=out.dtype)
+    panel = out[:m, :nb].copy()
+    panel[:nb] = np.tril(panel[:nb])
+    low = np.tril(out[nb:m, nb:m])
+    schur = low + low.T - np.diag(np.diag(low))
     return panel, schur
 
 
-@partial(jax.jit, static_argnames=("nbp", "interpret", "mesh"))
+@partial(jax.jit, static_argnames=("interpret", "mesh"))
 def _batched_front_factor(
-    fronts: jax.Array, nbp: int, interpret: bool, mesh=None
+    fronts: jax.Array, nb: jax.Array, interpret: bool, mesh=None
 ) -> jax.Array:
-    def factor(x):
-        return jax.vmap(lambda f: front_factor_vmem(f, nbp, interpret=interpret))(x)
+    def factor(x, n):
+        return front_factor_vmem(x, n, interpret=interpret)
 
     if mesh is None:
-        return factor(fronts)
+        return factor(fronts, nb)
     # GSPMD cannot partition a Mosaic call; shard_map gives each device
     # its own lanes of the batch (lanes are independent fronts)
     spec = PartitionSpec(mesh.axis_names[0])
     return jax.shard_map(
-        factor, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
-    )(fronts)
+        factor, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
+        check_vma=False,
+    )(fronts, nb)
 
 
 def batched_front_factor(
     fronts: jax.Array,
-    nbp: int,
+    nb,
     interpret: Optional[bool] = None,
     mesh=None,
 ) -> jax.Array:
-    """Factor a (B, mp, mp) stack of padded fronts in one vmapped kernel.
+    """Factor a (B, mp, mp) stack of padded fronts in one kernel call.
 
-    Requires mp ≤ VMEM_FRONT_MAX (the executor routes larger fronts through
-    the per-front panel pipeline of ``partial_cholesky``).  With a 1-D
-    ``mesh`` the batch axis is split over its devices (B must be a
-    multiple of the mesh size); each device factors its own lanes.
+    ``nb`` is each lane's pivot count, B ints (or one int for every
+    lane).  Requires mp ≤ VMEM_FRONT_MAX (the executor routes larger
+    fronts through the per-front panel pipeline of ``partial_cholesky``).
+    With a 1-D ``mesh`` the batch axis is split over its devices (B must
+    be a multiple of the mesh size); each device factors its own lanes.
     """
     b, mp, mp2 = fronts.shape
-    assert mp == mp2 and mp <= VMEM_FRONT_MAX and nbp % TILE == 0
-    return _batched_front_factor(fronts, nbp, should_interpret(interpret), mesh)
+    assert mp == mp2 and mp <= VMEM_FRONT_MAX
+    if not isinstance(nb, jax.Array):
+        nb = np.broadcast_to(np.asarray(nb, np.int32), (b,))
+    return _batched_front_factor(fronts, nb, should_interpret(interpret), mesh)

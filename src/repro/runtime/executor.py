@@ -13,7 +13,7 @@ memory accounting) and produce **bit-identical factors**:
    per-front state machine of ``repro.online.state`` made real.  A front is
    *ready* the instant the last of its children's Schur complements lands;
    ready fronts of the same padded shape class are opportunistically
-   coalesced into one vmapped Pallas dispatch (up to ``max_batch``), each
+   coalesced into one batched Pallas dispatch (up to ``max_batch``), each
    dispatch's device group is carved incrementally from the currently free
    devices (:class:`~repro.distributed.device_groups.BuddyAllocator`), and
    the dispatch is issued on a worker thread immediately — extend-add and
@@ -166,6 +166,8 @@ class ExecutionReport:
     # perf_counter() at the start of the timed run: trace times are
     # seconds since then, so the window is [t_origin, + measured_makespan]
     t_origin: float = math.nan
+    # bytes of the padded whole-front stacks sent to the device
+    batch_bytes: int = 0
 
     # ------------------------------------------------------------------
     def total_flops(self) -> float:
@@ -277,6 +279,9 @@ class ExecutionReport:
         lat = self.mean_ready_latency()
         if lat is not None:
             lines.append(f"ready latency      {lat*1e3:9.2f} ms mean")
+        lines.append(
+            f"whole-front stacks {self.batch_bytes/2**20:9.2f} MiB sent"
+        )
         if self.projected_peak_bytes > 0:
             lines.append(
                 f"peak memory        {self.measured_peak_bytes/2**20:9.2f} MiB"
@@ -442,6 +447,9 @@ class PlanExecutor:
             if sn.parent >= 0:
                 self._children[sn.parent].append(s)
 
+        # bytes of each whole-front stack sent this run (list.append is
+        # atomic, so worker threads append without a lock)
+        self._batch_sent: List[int] = []
         self._prov = provenance
         if provenance is not None:
             self._build_groups(provenance)
@@ -554,43 +562,55 @@ class PlanExecutor:
 
     # ------------------------------------------------------------------
     def _run_batch(
-        self, batch: np.ndarray, nbp: int, group_devices: List
+        self, batch: np.ndarray, nb: np.ndarray, group_devices: List
     ) -> np.ndarray:
-        """Factor a (B, mp, mp) padded stack, sharded over ``group_devices``
-        when more than one is available and sharding is enabled; returns
-        the factored stack (host)."""
+        """Factor a (B, mp, mp) padded stack whose lanes have ``nb`` pivots
+        each, sharded over ``group_devices`` when more than one is
+        available and sharding is enabled; returns the factored stack
+        (host)."""
         mp = batch.shape[1]
         assert mp <= VMEM_FRONT_MAX, "large fronts take the per-front path"
+        nb = np.asarray(nb, np.int32)
         if len(group_devices) > 1 and self.shard_dispatch:
             ndev = len(group_devices)
             pad = (-batch.shape[0]) % ndev
             x = batch
-            if pad:
+            if pad:  # identity lanes with no pivots
                 eye = np.broadcast_to(
                     np.eye(mp, dtype=batch.dtype), (pad, mp, mp)
                 )
                 x = np.concatenate([batch, eye], axis=0)
+                nb = np.concatenate([nb, np.zeros(pad, np.int32)])
+            self._batch_sent.append(x.nbytes)
             mesh = Mesh(np.array(group_devices), ("front",))
             # host → each device's own lanes; the kernel runs under
             # shard_map, one Mosaic call per device on its local lanes
             with stage("executor.h2d"):
-                x = jax.device_put(
-                    x, NamedSharding(mesh, PartitionSpec("front"))
+                x, nb = jax.device_put(
+                    (x, nb), NamedSharding(mesh, PartitionSpec("front"))
                 )
             with stage("executor.kernel"):
                 out = jax.block_until_ready(
-                    batched_front_factor(x, nbp, self.interpret, mesh=mesh)
+                    batched_front_factor(x, nb, self.interpret, mesh=mesh)
                 )
             with stage("executor.d2h"):
                 return np.asarray(out)[: batch.shape[0]]
+        self._batch_sent.append(batch.nbytes)
         with stage("executor.h2d"):
-            x = jax.device_put(batch, group_devices[0])
+            x, nb = jax.device_put((batch, nb), group_devices[0])
         with stage("executor.kernel"):
             out = jax.block_until_ready(
-                batched_front_factor(x, nbp, self.interpret)
+                batched_front_factor(x, nb, self.interpret)
             )
         with stage("executor.d2h"):
             return np.asarray(out)
+
+    def _pivots(self, supernodes: Sequence[int]) -> np.ndarray:
+        """Each front's pivot count: the whole-front kernel's per-lane
+        operand."""
+        return np.array(
+            [self.symb.supernodes[s].nb for s in supernodes], np.int32
+        )
 
     def _run_large(self, front: np.ndarray, nb: int, device):
         """One front above VMEM_FRONT_MAX through the panel pipeline on
@@ -631,8 +651,9 @@ class PlanExecutor:
             if sig in seen:
                 continue
             seen.add(sig)
-            eye = np.broadcast_to(np.eye(mp, dtype=self.dtype), (len(d.supernodes), mp, mp)).copy()
-            self._run_batch(eye, nbp, devs)
+            k = len(d.supernodes)
+            eye = np.broadcast_to(np.eye(mp, dtype=self.dtype), (k, mp, mp)).copy()
+            self._run_batch(eye, np.full(k, nbp), devs)
 
     def _warmup_async(self) -> None:
         """Compile the async runner's dispatch signatures (untimed).
@@ -648,9 +669,6 @@ class PlanExecutor:
             key = padded_shape(sn.m, sn.nb)
             if key[0] <= VMEM_FRONT_MAX:
                 counts[key] = counts.get(key, 0) + 1
-        # single-device batches run on whichever device their group was
-        # carved from, and each placement is its own executable
-        devices = self.devices if self.shard_dispatch else self.devices[:1]
         for (mp, nbp), c in sorted(counts.items()):
             b = 1
             cap = _pow2_ceil(min(c, self.max_batch))
@@ -658,8 +676,11 @@ class PlanExecutor:
                 eye = np.broadcast_to(
                     np.eye(mp, dtype=self.dtype), (b, mp, mp)
                 ).copy()
-                for dev in devices:
-                    self._run_batch(eye, nbp, [dev])
+                # single-device batches run on whichever device their
+                # group was carved from, and each placement is its own
+                # executable
+                for dev in self.devices:
+                    self._run_batch(eye, np.full(b, nbp), [dev])
                 b *= 2
 
     def _dispatch_devices(
@@ -811,6 +832,7 @@ class PlanExecutor:
             projected_peak_bytes=float(projected_peak),
             mode=mode,
             t_origin=self._obs_t0,
+            batch_bytes=int(sum(self._batch_sent)),
         )
 
     # -- wave runner (legacy, barrier-synchronous) ---------------------
@@ -836,6 +858,7 @@ class PlanExecutor:
         # realization of the schedule's memory timeline)
         self._mem_panels = 0.0
         self._mem_updates = 0.0
+        self._batch_sent = []
         mem_peak = 0.0
         t_run0 = time.perf_counter()
         self._obs_t0 = t_run0
@@ -855,7 +878,7 @@ class PlanExecutor:
             )
             self._mem_updates -= consumed
 
-            mp, nbp = d.key
+            mp = d.key[0]
             disp_devs = self._dispatch_devices(d.supernodes, groups)
             if not self.shard_dispatch:
                 disp_devs = disp_devs[:1]
@@ -885,7 +908,9 @@ class PlanExecutor:
                     + fronts_bytes
                     + float(batch.nbytes),
                 )
-                out = self._run_batch(batch, nbp, disp_devs)
+                out = self._run_batch(
+                    batch, self._pivots(d.supernodes), disp_devs
+                )
                 t1 = time.perf_counter() - t_run0
                 for s, o in zip(d.supernodes, out):
                     sn = symb.supernodes[s]
@@ -971,6 +996,7 @@ class PlanExecutor:
         t_ready: Dict[int, float] = {}
         self._mem_panels = 0.0
         self._mem_updates = 0.0
+        self._batch_sent = []
         mem_inflight = 0.0
         mem_peak = 0.0
         n_done = 0
@@ -1026,13 +1052,13 @@ class PlanExecutor:
                 t_ready[s] = 0.0
                 ready.push(s)
 
-        def worker_small(batch, nbp, devs, delay):
+        def worker_small(batch, nb, devs, delay):
             with stage("executor.dispatch"):
                 t0 = now()
                 if delay > 0:
                     time.sleep(delay)  # the straggling device — only this
                     # dispatch's ancestors wait for it
-                out = self._run_batch(batch, nbp, devs)
+                out = self._run_batch(batch, nb, devs)
                 return {"out": out, "t0": t0, "t1": now()}
 
         def worker_large(items, device, delay):
@@ -1056,7 +1082,7 @@ class PlanExecutor:
                 if alloc.n_free == 0:
                     break
                 key, members = ready.pop_batch()
-                mp, nbp = key
+                mp = key[0]
 
                 def dispatch_bytes(ms) -> float:
                     fb = sum(
@@ -1158,7 +1184,9 @@ class PlanExecutor:
                     if not self.shard_dispatch:
                         devs = devs[:1]
                     disp_dev = len(devs)
-                    fut = pool.submit(worker_small, batch, nbp, devs, delay)
+                    fut = pool.submit(
+                        worker_small, batch, self._pivots(members), devs, delay
+                    )
                 del fronts
                 mem_inflight += held
                 in_flight[fut] = _Inflight(
@@ -1270,9 +1298,9 @@ class PlanExecutor:
         ``ext_cb`` holds the Schur complements crossing into the group
         from already-finished external children.  Levels run children
         before parents; within a level, same-shape small members factor
-        as **one padded vmapped dispatch** (identity lanes up to the next
+        as **one padded batched dispatch** (identity lanes up to the next
         power of two, so every batch signature was pre-compiled by
-        ``_warmup_async``; vmap lanes are independent, so batching never
+        ``_warmup_async``; lanes are independent, so batching never
         changes a front's bits) and each member still gathers its entries
         and folds its children in tree order — the bit-identity
         discipline of ``_assemble``, unchanged.
@@ -1340,7 +1368,7 @@ class PlanExecutor:
                 sn = symb.supernodes[s]
                 classes.setdefault(padded_shape(sn.m, sn.nb), []).append(s)
             for key in sorted(classes):
-                mp, nbp = key
+                mp = key[0]
                 sns = classes[key]
                 if mp > VMEM_FRONT_MAX:
                     for s in sns:
@@ -1374,7 +1402,9 @@ class PlanExecutor:
                             )
                             batch = np.concatenate([batch, eye], axis=0)
                     peak = max(peak, held + float(batch.nbytes))
-                    out = self._run_batch(batch, nbp, devs)
+                    nb = np.zeros(kp, np.int32)  # identity lanes: no pivots
+                    nb[:k] = self._pivots(chunk)
+                    out = self._run_batch(batch, nb, devs)
                     with stage("executor.unpack"):
                         for s, o in zip(chunk, out[:k]):
                             sn = symb.supernodes[s]
@@ -1454,6 +1484,7 @@ class PlanExecutor:
         n_disp = 0
         self._mem_panels = 0.0
         self._mem_updates = 0.0
+        self._batch_sent = []
         mem_peak = 0.0
         t_run0 = time.perf_counter()
         self._obs_t0 = t_run0
@@ -1546,6 +1577,7 @@ class PlanExecutor:
         ready: List[int] = []
         self._mem_panels = 0.0
         self._mem_updates = 0.0
+        self._batch_sent = []
         mem_inflight = 0.0
         mem_peak = 0.0
         n_done = 0

@@ -274,7 +274,7 @@ def test_bit_identity_matrix_optimized(problem):
     still assembles (extend-add in tree order) and factors at its own
     padded shape class — so all six legs must agree bit-for-bit.  The
     sequential leg routes ``factorize`` through the *executor's* kernel
-    path (pad → batched vmap factor → extract), not the jnp reference
+    path (pad → batched factor → extract), not the jnp reference
     kernel, so it is the same arithmetic by construction.
     """
     import jax.numpy as jnp
@@ -296,13 +296,13 @@ def test_bit_identity_matrix_optimized(problem):
     def kernel_factor(f, nb):
         # the executor's small-front path, one-lane batch
         fh = np.asarray(f)
-        mp, nbp = padded_shape(fh.shape[0], nb)
+        mp, _ = padded_shape(fh.shape[0], nb)
         if mp > VMEM_FRONT_MAX:
             return partial_cholesky(f, nb, interpret=interpret)
         batch = pad_front_np(fh, nb, fh.dtype)[None]
         out = np.asarray(
             jax.block_until_ready(
-                batched_front_factor(jnp.asarray(batch), nbp, interpret)
+                batched_front_factor(jnp.asarray(batch), [nb], interpret)
             )
         )
         return extract_panel_schur(out[0], fh.shape[0], nb)
@@ -534,13 +534,19 @@ def test_ready_queue_replays_regrouping(seed):
 @pytest.fixture(scope="module")
 def mixed_problem():
     """A forest with three padded shape classes: a random sparse SPD block
-    (min-degree order; (256, 128) fronts and (128, 128) roots) beside a
-    dense 130 x 130 block (one (256, 256) front)."""
+    (min-degree order; (128, 128) fronts) beside two dense 10-node leaves
+    over a dense 130-node clique (one (256, 128) leaf front of 10 pivots
+    and a 130-row border; one (256, 256) front of the clique and the
+    other leaf)."""
     rng = np.random.default_rng(0)
     r = random_spd(120, 4.0, rng)
     r = permute_symmetric(r, min_degree(r))
-    d = rng.uniform(-1.0, 1.0, (130, 130))
-    d = d @ d.T + 130.0 * np.eye(130)
+    n = 150
+    d = n * np.eye(n)
+    for leaf in (range(0, 10), range(10, 20)):
+        idx = np.r_[leaf, 20:n]
+        g = rng.uniform(-1.0, 1.0, (idx.size, idx.size))
+        d[np.ix_(idx, idx)] += (g + g.T) / 2
     ap = sp.block_diag([r, sp.csr_matrix(d)]).tocsr()
     symb = analyze(ap, relax=1)
     plan = make_plan(symb.task_tree(), 8, alpha=0.9)

@@ -107,6 +107,28 @@ def test_dispatch_schedule_batches_same_shapes(problem):
             assert padded_shape(sn.m, sn.nb) == d.key
 
 
+@pytest.mark.parametrize("mode", ["async", "waves"])
+def test_report_counts_whole_front_stack_bytes(problem, mode):
+    """``batch_bytes`` is Σ B·mp²·itemsize over the run's whole-front
+    dispatches (warm-up excluded), and starts from zero on every run."""
+    from repro.kernels.frontal_cholesky import VMEM_FRONT_MAX
+    from repro.kernels.ops import padded_shape
+
+    ap, symb, plan = problem
+    ex = PlanExecutor(symb, plan, mode=mode)
+    _, report = ex.run(ap, warmup=True)
+    per_dispatch = {}
+    for e in report.trace:
+        sn = symb.supernodes[e.front]
+        mp = padded_shape(sn.m, sn.nb)[0]
+        if mp <= VMEM_FRONT_MAX:
+            per_dispatch[(e.wave, e.t_start)] = e.batched * mp * mp * 8
+    assert per_dispatch
+    assert report.batch_bytes == sum(per_dispatch.values())
+    assert ex.run(ap, warmup=False)[1].batch_bytes == report.batch_bytes
+    assert "MiB sent" in report.summary()
+
+
 # ----------------------------------------------------------------------
 def test_pow2_floor():
     assert [pow2_floor(x) for x in (1, 2, 3, 7, 8, 9)] == [1, 2, 2, 4, 8, 8]

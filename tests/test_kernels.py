@@ -115,6 +115,54 @@ def test_padding_pivots_are_inert(rng):
     ) < 5e-5
 
 
+def _batched_parity(fronts, nbs, rtol=5e-5):
+    """pad_front_np → batched_front_factor (per-lane nb) → extract_panel_schur
+    against partial_cholesky_ref, lane by lane; the fronts share a class."""
+    classes = {ops.padded_shape(f.shape[0], nb) for f, nb in zip(fronts, nbs)}
+    assert len(classes) == 1
+    batch = np.stack([ops.pad_front_np(f, nb) for f, nb in zip(fronts, nbs)])
+    out = np.asarray(ops.batched_front_factor(jnp.asarray(batch), nbs, True))
+    for o, f, nb in zip(out, fronts, nbs):
+        m = f.shape[0]
+        pan, sch = ops.extract_panel_schur(o, m, nb)
+        pr, sr = (np.asarray(x) for x in partial_cholesky_ref(jnp.asarray(f), nb))
+        assert pan.shape == (m, nb) and sch.shape == (m - nb, m - nb)
+        assert np.abs(pan - pr).max() / max(1.0, np.abs(pr).max()) < rtol
+        if sch.size:
+            assert np.abs(sch - sr).max() / max(1.0, np.abs(sr).max()) < rtol
+
+
+@pytest.mark.parametrize(
+    "m,nb",
+    [(8, 1), (8, 8), (100, 3), (127, 127), (128, 1), (128, 128), (129, 1),
+     (129, 128), (200, 129), (256, 255), (1000, 37)],
+)
+def test_tight_front_layout_matches_ref(m, nb, rng):
+    """The whole-front path across the class edges: the front fills
+    [0, m)², and the kernel eliminates exactly its nb pivots."""
+    _batched_parity([_spd(m, rng)], [nb])
+
+
+def test_mixed_pivot_counts_in_one_class(rng):
+    """Lanes of one (256, 256) class with different orders and pivot
+    counts, each checked on its own."""
+    shapes = [(256, 255), (200, 129), (130, 130), (256, 256), (180, 150)]
+    _batched_parity([_spd(m, rng) for m, _ in shapes], [nb for _, nb in shapes])
+
+
+def test_padded_shape_never_exceeds_the_split_rule():
+    """The tight class is never larger than the old rule, which rounded
+    the pivot block and the border block up to 128 each."""
+    for m in range(1, 1101):
+        for nb in range(1, m + 1):
+            mb = m - nb
+            old_nbp = -(-nb // TILE) * TILE
+            old_mp = old_nbp + (-(-mb // TILE) * TILE)
+            mp, nbp = ops.padded_shape(m, nb)
+            assert mp <= old_mp and nbp == old_nbp and mp % TILE == 0
+            assert m <= mp < m + TILE
+
+
 @pytest.mark.parametrize(
     "kernel,shapes",
     [

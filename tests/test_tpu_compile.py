@@ -60,6 +60,14 @@ def _compile(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _batched(b):
+    """The executor's whole-front call on a (b, mp, mp) stack, with each
+    lane's pivot count a runtime operand."""
+    return lambda f: ops._batched_front_factor(
+        f, jnp.arange(1, b + 1, dtype=jnp.int32), False
+    )
+
+
 def _custom_calls(compiled) -> list:
     return [
         ln for ln in compiled.as_text().splitlines()
@@ -73,12 +81,14 @@ def test_front_factor_vmem_compiles(one_chip, mp):
     assert len(_custom_calls(c)) == 1
 
 
-def test_batched_front_factor_compiles(one_chip):
-    c = _compile(
-        lambda f: ops._batched_front_factor(f, 256, False), (8, 512, 512),
-        sharding=one_chip,
-    )
-    assert len(_custom_calls(c)) == 1
+@pytest.mark.parametrize("shape", [(32, 128, 128), (8, 1024, 1024)])
+def test_batched_front_factor_compiles(one_chip, shape):
+    """The scalar-prefetched kernel, its pivot counts in SMEM, at the
+    128 class and the largest whole-front class."""
+    c = _compile(_batched(shape[0]), shape, sharding=one_chip)
+    calls = _custom_calls(c)
+    assert len(calls) == 1
+    assert f"s32[{shape[0]}]" in calls[0]  # the per-lane pivot counts
 
 
 def test_panel_factor_compiles(one_chip):
@@ -104,8 +114,7 @@ def test_large_front_pipeline_compiles(one_chip):
 @pytest.mark.parametrize(
     "fn,shape,names",
     [
-        (lambda f: ops._batched_front_factor(f, 256, False), (8, 512, 512),
-         ["front_factor_vmem"]),
+        (_batched(32), (32, 128, 128), ["front_factor_vmem"]),
         (lambda f: ops._partial_cholesky_impl(f, 256, False), (1280, 1280),
          ["panel_factor", "syrk_downdate"]),
     ],
@@ -114,7 +123,7 @@ def test_large_front_pipeline_compiles(one_chip):
 def test_kernel_names_reach_the_compiled_custom_calls(one_chip, fn, shape, names):
     """The HLO name of each Pallas custom call (what a device trace's
     ``XLA Ops`` line shows) carries the kernel's ``name=``:
-    ``%vmap_front_factor_vmem_.1``, ``%panel_factor.2``, ..."""
+    ``%front_factor_vmem.1``, ``%panel_factor.2``, ..."""
     c = _compile(fn, shape, sharding=one_chip)
     ops_ = [ln.split(" = ", 1)[0].split()[-1] for ln in _custom_calls(c)]
     assert len(ops_) == len(names)
@@ -129,16 +138,18 @@ def test_sharded_batch_runs_one_kernel_per_device_without_gather(topo):
     mesh = Mesh(np.array(topo.devices), ("front",))
     sharding = NamedSharding(mesh, PartitionSpec("front"))
     x = jax.ShapeDtypeStruct((8, 512, 512), jnp.float32, sharding=sharding)
+    nb = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=sharding)
     c = ops._batched_front_factor.lower(
-        x, nbp=256, interpret=False, mesh=mesh
+        x, nb, interpret=False, mesh=mesh
     ).compile()
     calls = _custom_calls(c)
     assert len(calls) == 1  # the per-device SPMD program
     assert "f32[2,512,512]" in calls[0]  # its local lanes: 8 / 4
+    assert "s32[2]" in calls[0]  # and their pivot counts
     text = c.as_text()
     for collective in ("all-gather", "all-to-all", "all-reduce", "collective-permute"):
         assert collective not in text
     assert c.output_shardings.is_equivalent_to(sharding, 3)
     with pytest.raises(NotImplementedError, match="shard_map"):
-        ops._batched_front_factor.lower(x, nbp=256, interpret=False).compile()
+        ops._batched_front_factor.lower(x, nb, interpret=False).compile()
 
